@@ -33,13 +33,15 @@ def _parse_row(line: str) -> dict:
 
 def _metadata(args) -> dict:
     import jax
+
+    from repro.kernels import interpret_mode
     return {
         "git_sha": _git_sha(),
         "jax_version": jax.__version__,
         "backend_platform": jax.default_backend(),
-        # the Pallas kernels run with interpret=True everywhere off-TPU
-        # (see repro.kernels): absolute µs characterize the host
-        "pallas_interpret_mode": jax.default_backend() != "tpu",
+        # off-TPU the Pallas kernels run in the interpreter, so absolute
+        # µs characterize the host
+        "pallas_interpret_mode": interpret_mode(),
         "timestamp_utc": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
         "quick": bool(args.quick),
         "only": args.only,
@@ -56,6 +58,9 @@ def main(argv=None) -> None:
                     help="trajectory file path (default: "
                          "BENCH_<utc-timestamp>.json in the cwd)")
     args = ap.parse_args(argv)
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from .common import workloads
     from .paper_figs import (fig10_hitrate, fig7_speedup, fig8_energy,

@@ -24,6 +24,7 @@ import numpy as np
 from repro import FrameTracker, compile_model
 from repro.core.workload import PointNetConfig, SALayerSpec
 from repro.data.pointcloud import request_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import (PointCloudServable, ServingEngine,
                                 ShapeBuckets, VirtualClock)
 from repro.models import pointnet2 as pn
@@ -37,6 +38,7 @@ def main():
     ap.add_argument("--backend", default="reram-fused")
     ap.add_argument("--frames", type=int, default=18)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = PointNetConfig(name="lidar-demo", n_points=64, layers=(
         SALayerSpec(n_centers=24, n_neighbors=4, in_features=4,

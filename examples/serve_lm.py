@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import generate
 from repro.models import lm
 
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--new", type=int, default=24)
     ap.add_argument("--temperature", type=float, default=0.8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     params = lm.init(jax.random.PRNGKey(0), cfg)

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from repro import compile_model
 from repro.core.workload import PointNetConfig, SALayerSpec
 from repro.data.pointcloud import request_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import PointCloudServable, ServingEngine, ShapeBuckets
 from repro.models import pointnet2 as pn
 
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--schedule", default="pointer")
     ap.add_argument("--requests", type=int, default=24)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = PointNetConfig(name="serve-demo", n_points=64, layers=(
         SALayerSpec(n_centers=24, n_neighbors=4, in_features=4,

@@ -5,8 +5,9 @@ Two layers, one front door (``tools/check_static.py``, the CI gate):
 
 * :mod:`repro.analysis.lint` — AST linter whose rules encode this
   repo's actual bug history (wall-clock in deterministic tiers,
-  unseeded randomness, host sync reachable from jitted paths, pinned
-  ``interpret=True``, bare excepts, unfrozen pytree dataclasses).
+  unseeded randomness, host sync reachable from jitted paths, a Pallas
+  ``interpret=`` flag not derived from the platform, bare excepts,
+  unfrozen pytree dataclasses).
   Rules live in a decorator registry (:func:`register_rule`) like the
   backend registry, so new bug classes become new rules.
 * :mod:`repro.analysis.trace` — lowers a ``CompiledModel`` to jaxpr /

@@ -395,30 +395,36 @@ def rule_host_sync(mod: Module) -> Iterator[Finding]:
 
 
 # ---------------------------------------------------------------------------
-# rule: interpret=True pinned at a pallas_call site
+# rule: a pallas_call whose interpret flag is not the derived one
 # ---------------------------------------------------------------------------
 
 @register_rule(
     "interpret-pinned",
-    history="All kernel claims were interpret-mode for the first six PRs; "
-            "the real-TPU validation item (ROADMAP) dies the moment a "
-            "pallas_call hardcodes interpret=True instead of threading the "
-            "caller's flag — the site silently never runs compiled.")
+    history="Every kernel wrapper once defaulted to interpret=True and "
+            "threaded the flag, so on a TPU the kernels would have run in "
+            "the Pallas interpreter. "
+            "The flag now has one source, "
+            "repro.kernels.platform.interpret_mode(), derived from the "
+            "platform; a pallas_call that pins it, threads it from a "
+            "parameter or omits it can run the interpreter on the chip.")
 def rule_interpret_pinned(mod: Module) -> Iterator[Finding]:
     for call in mod.calls():
         dotted = mod.resolve(call.func)
-        if dotted is None or not dotted.endswith("pallas_call"):
+        if dotted is None or not dotted.endswith("pallas_call") \
+                or mod.allowed(call.lineno, "interpret-pinned"):
             continue
-        for kw in call.keywords:
-            if kw.arg == "interpret" \
-                    and isinstance(kw.value, ast.Constant) \
-                    and kw.value.value is True \
-                    and not mod.allowed(call.lineno, "interpret-pinned"):
-                yield mod.finding(
-                    "interpret-pinned", kw.value,
-                    "pallas_call site hardcodes interpret=True; thread an "
-                    "interpret: bool parameter so the kernel can run "
-                    "compiled on real hardware")
+        flag = next((kw.value for kw in call.keywords
+                     if kw.arg == "interpret"), None)
+        fn = flag.func if isinstance(flag, ast.Call) else None
+        derived = (fn is not None and not flag.args and not flag.keywords
+                   and getattr(fn, "id", getattr(fn, "attr", None))
+                   == "interpret_mode")
+        if not derived:
+            yield mod.finding(
+                "interpret-pinned", call if flag is None else flag,
+                "pallas_call site does not pass interpret=interpret_mode() "
+                "(repro.kernels.platform): the flag must come from the "
+                "platform, so a TPU never runs the interpreter")
 
 
 # ---------------------------------------------------------------------------

@@ -31,6 +31,7 @@ from typing import Any, Callable, Iterator
 
 import jax
 import numpy as np
+from jax.extend import core as jex_core
 
 from repro.kernels.program import VMEM_BUDGET_BYTES
 from repro.launch import hlo_analysis as _ha
@@ -167,9 +168,9 @@ class ContractReport:
 # ---------------------------------------------------------------------------
 
 def _subjaxprs(value: Any) -> Iterator[Any]:
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, jex_core.ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, jex_core.Jaxpr):
         yield value
     elif isinstance(value, (tuple, list)):
         for v in value:

@@ -11,23 +11,29 @@
                    irregular access that contributions 2/3 optimize)
 - ``fps_update`` : FPS distance relaxation (front-end hot loop)
 
-Every kernel has a pure-jnp oracle in ``ref.py`` and a jit'd public wrapper
-in ``ops.py``; they are validated on CPU with ``interpret=True`` and target
-TPU (BlockSpec VMEM tiling, 128-aligned) for deployment.
+Every kernel has a pure-jnp oracle in ``ref.py`` and a jit'd public wrapper.
+``platform.interpret_mode`` decides, from the platform alone, how every
+``pallas_call`` runs: in the Pallas interpreter on CPU (the test suite),
+compiled by Mosaic on a TPU (``chip_smoke.py`` serves model0-2 that way;
+``tests/test_tpu_compile.py`` compiles the main-path kernels for a v5e
+without one).
 """
-from .fused_mlp import reram_mlp_fused, reram_mlp_fused_batched
+from .fused_mlp import (chain_quantizer, reram_mlp_fused,
+                        reram_mlp_fused_batched, requantize)
 from .ops import (aggregate_diff, aggregate_diff_batched,
-                  count_dma_elisions, encode_planes, fps, fps_update, on_tpu,
+                  count_dma_elisions, encode_planes, fps, fps_update,
                   quantize_tensor, reram_linear)
+from .platform import interpret_mode
 from .program import (FUSED_MODES, CrossbarProgram, FusedPlan, build_program,
                       fused_vmem_bytes, plan_fused_mlp)
 from .reram_mlp import reram_matmul_int
 
 __all__ = [
     "CrossbarProgram", "FUSED_MODES", "FusedPlan", "aggregate_diff",
+    "chain_quantizer",
     "aggregate_diff_batched",
     "build_program", "count_dma_elisions", "encode_planes", "fps",
-    "fps_update", "fused_vmem_bytes", "on_tpu", "plan_fused_mlp",
-    "quantize_tensor", "reram_linear", "reram_matmul_int", "reram_mlp_fused",
-    "reram_mlp_fused_batched",
+    "fps_update", "fused_vmem_bytes", "interpret_mode", "plan_fused_mlp",
+    "quantize_tensor", "requantize", "reram_linear", "reram_matmul_int",
+    "reram_mlp_fused", "reram_mlp_fused_batched",
 ]

@@ -15,57 +15,74 @@ the TPU-native twin of the paper's buffer hit rate.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .platform import interpret_mode
 
 __all__ = ["aggregate_diff", "aggregate_diff_batched"]
 
 
 def _kernel(nbr_ref, ctr_ref, f_nbr_ref, f_ctr_ref, o_ref):
     del nbr_ref, ctr_ref  # only used by the index_maps
-    o_ref[...] = (f_nbr_ref[...] - f_ctr_ref[...])[None]
+    o_ref[...] = f_nbr_ref[...] - f_ctr_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def aggregate_diff(features: jnp.ndarray, nbr_idx: jnp.ndarray,
-                   ctr_idx: jnp.ndarray, *,
-                   interpret: bool = True) -> jnp.ndarray:
-    """features (N, C); nbr_idx (M, K) int32; ctr_idx (M,) int32
-    -> (M, K, C) with out[i, j] = features[nbr_idx[i, j]] - features[ctr_idx[i]].
-    C should be a multiple of 128 on real TPU (lane width)."""
-    n, c = features.shape
-    m, k = nbr_idx.shape
+def _gather_diff(features, nbr_idx, ctr_idx, *, name: str):
+    """features (B, N, C); nbr_idx (B, M, K); ctr_idx (B, M) -> (B, M, K, C).
+
+    Layout: Mosaic tiles the last two dims of a block (8 sublanes x 128
+    lanes) and accepts a block dim that is not a tile multiple only when it
+    spans the whole array dim. One feature row per grid step therefore
+    rides a unit axis: features are viewed as (B, N, 1, C) and every block
+    is (1, C) in its last two dims. The index streams are flattened to 1-D
+    before they are prefetched into SMEM, where a 2-D or 3-D array would
+    pad its minor dims."""
+    b, n, c = features.shape
+    if nbr_idx.shape[0] != b or ctr_idx.shape[0] != b:
+        raise ValueError(f"batch mismatch: features {features.shape}, "
+                         f"nbr {nbr_idx.shape}, ctr {ctr_idx.shape}")
+    _, m, k = nbr_idx.shape
+    rows = features.reshape(b, n, 1, c)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(m, k),
+        grid=(b, m, k),
         in_specs=[
-            pl.BlockSpec((1, c), lambda i, j, nbr, ctr: (nbr[i, j], 0)),
-            pl.BlockSpec((1, c), lambda i, j, nbr, ctr: (ctr[i], 0)),
+            pl.BlockSpec((None, None, 1, c),
+                         lambda bi, i, j, nbr, ctr:
+                         (bi, nbr[(bi * m + i) * k + j], 0, 0)),
+            pl.BlockSpec((None, None, 1, c),
+                         lambda bi, i, j, nbr, ctr:
+                         (bi, ctr[bi * m + i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, c), lambda i, j, nbr, ctr: (i, j, 0)),
+        out_specs=pl.BlockSpec((None, None, None, 1, c),
+                               lambda bi, i, j, nbr, ctr: (bi, i, j, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _kernel,
-        name="aggregate_diff",
+        name=name,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, k, c), features.dtype),
-        interpret=interpret,
-    )(nbr_idx, ctr_idx, features, features)
+        out_shape=jax.ShapeDtypeStruct((b, m, k, 1, c), features.dtype),
+        interpret=interpret_mode(),
+    )(nbr_idx.reshape(-1), ctr_idx.reshape(-1), rows, rows)
+    return out.reshape(b, m, k, c)
 
 
-def _kernel_batched(nbr_ref, ctr_ref, f_nbr_ref, f_ctr_ref, o_ref):
-    del nbr_ref, ctr_ref  # only used by the index_maps
-    o_ref[...] = (f_nbr_ref[...] - f_ctr_ref[...])[None]
+@jax.jit
+def aggregate_diff(features: jnp.ndarray, nbr_idx: jnp.ndarray,
+                   ctr_idx: jnp.ndarray) -> jnp.ndarray:
+    """features (N, C); nbr_idx (M, K) int32; ctr_idx (M,) int32
+    -> (M, K, C) with out[i, j] = features[nbr_idx[i, j]] - features[ctr_idx[i]].
+    The single-cloud launch of the :func:`aggregate_diff_batched` kernel."""
+    return _gather_diff(features[None], nbr_idx[None], ctr_idx[None],
+                        name="aggregate_diff")[0]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def aggregate_diff_batched(features: jnp.ndarray, nbr_idx: jnp.ndarray,
-                           ctr_idx: jnp.ndarray, *,
-                           interpret: bool = True) -> jnp.ndarray:
+                           ctr_idx: jnp.ndarray) -> jnp.ndarray:
     """Batch-gridded :func:`aggregate_diff`: the whole batch of same-shape
     plan-ordered gathers in ONE ``pallas_call`` with a leading batch grid
     axis — the launch shape batched plan-driven execution
@@ -80,27 +97,5 @@ def aggregate_diff_batched(features: jnp.ndarray, nbr_idx: jnp.ndarray,
     unbatched kernel, so a plan-ordered index stream elides the same
     HBM→VMEM copies; the batch axis is outermost and never interleaves
     two clouds' streams."""
-    b, n, c = features.shape
-    if nbr_idx.shape[0] != b or ctr_idx.shape[0] != b:
-        raise ValueError(f"batch mismatch: features {features.shape}, "
-                         f"nbr {nbr_idx.shape}, ctr {ctr_idx.shape}")
-    _, m, k = nbr_idx.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, m, k),
-        in_specs=[
-            pl.BlockSpec((1, 1, c),
-                         lambda bi, i, j, nbr, ctr: (bi, nbr[bi, i, j], 0)),
-            pl.BlockSpec((1, 1, c),
-                         lambda bi, i, j, nbr, ctr: (bi, ctr[bi, i], 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, 1, c), lambda bi, i, j, nbr, ctr: (bi, i, j, 0)),
-    )
-    return pl.pallas_call(
-        _kernel_batched,
-        name="aggregate_diff_batched",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, m, k, c), features.dtype),
-        interpret=interpret,
-    )(nbr_idx, ctr_idx, features, features)
+    return _gather_diff(features, nbr_idx, ctr_idx,
+                        name="aggregate_diff_batched")

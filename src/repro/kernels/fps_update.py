@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import interpret_mode
+
 __all__ = ["fps_update"]
 
 
@@ -23,10 +25,9 @@ def _kernel(pts_ref, c_ref, d_ref, o_ref):
     o_ref[...] = jnp.minimum(d_ref[...], d_new)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_n",))
 def fps_update(points_t: jnp.ndarray, centroid: jnp.ndarray,
-               dist: jnp.ndarray, *, block_n: int = 512,
-               interpret: bool = True) -> jnp.ndarray:
+               dist: jnp.ndarray, *, block_n: int = 512) -> jnp.ndarray:
     """points_t (3, N); centroid (3, 1); dist (1, N) -> relaxed dist (1, N)."""
     _, n = points_t.shape
     bn = min(block_n, n)
@@ -42,5 +43,5 @@ def fps_update(points_t: jnp.ndarray, centroid: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n), dist.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(points_t, centroid, dist)

@@ -37,8 +37,8 @@ Three orderings make every tiling exact:
 
 - *Input snapshot*: layer ``l`` both reads stripe ``i`` of the
   activation panel (as its input) and writes it (as its output). At each
-  stripe's first N-tile the requantized input is snapshotted (int32
-  scratch for the i-outer modes, the int8 panel for 'wstat') so later
+  stripe's first N-tile the requantized input is snapshotted (an int8
+  stripe for the i-outer modes, the int8 panel for 'wstat') so later
   N-tiles never see half-overwritten rows.
 - *Scale finalization*: the running max over layer ``l``'s masked
   outputs (SMEM scratch) accumulates over every tile and finalizes into
@@ -81,14 +81,27 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .program import CrossbarProgram, plan_fused_mlp, quantize_tensor
+from .platform import interpret_mode
+from .program import (CROSSBAR, VMEM_BUDGET_BYTES, CrossbarProgram,
+                      _ceil_to, plan_fused_mlp, quantize_tensor)
+from .ref import combine_planes
 
-__all__ = ["reram_mlp_fused", "reram_mlp_fused_batched"]
+__all__ = ["reram_mlp_fused", "reram_mlp_fused_batched", "requantize",
+           "chain_quantizer"]
 
 DEFAULT_BLOCK_M = 128   # activation stripe height (crossbar geometry)
+
+# Mosaic's scoped-VMEM limit for the fused launches. ``plan_fused_mlp``
+# budgets the modelled buffers at VMEM_BUDGET_BYTES; Mosaic's own matmul
+# temporaries and the lane padding of the ``(rows, 1)`` row-sum scratch
+# come on top (model2 SA-1 'wstat' needs 16.3 MiB in all on a v5e, whose
+# VMEM is 128 MiB), so the limit leaves the same amount again as headroom.
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=2 * VMEM_BUDGET_BYTES)
 
 
 def _plane_matmul(x_int, planes_ref, row_sums, *, n_planes: int,
@@ -96,16 +109,19 @@ def _plane_matmul(x_int, planes_ref, row_sums, *, n_planes: int,
     """Bit-sliced crossbar matmul on one ``(bm, d) @ (d, bn)`` tile:
     shift-and-add over the 2-bit cell planes with a K-loop bounding each
     MXU op to ``(bm, bk) @ (bk, bn)``, then the offset-binary correction
-    from the pre-reduced input row sums."""
+    from the pre-reduced input row sums. Both operands are int8 (the
+    requantized activations fit it, the cells are 0..3): int8 x int8 ->
+    int32 is the MXU's integer mode, and Mosaic has no int32 matmul."""
     bm, d = x_int.shape
     bn = planes_ref.shape[-1]
     acc = jnp.zeros((bm, bn), jnp.int32)
     for p in range(n_planes):
         part = jnp.zeros((bm, bn), jnp.int32)
         for k0 in range(0, d, block_k):
-            w = planes_ref[0, p, k0:k0 + block_k, :].astype(jnp.int32)
             part = part + jax.lax.dot_general(
-                x_int[:, k0:k0 + block_k], w, (((1,), (0,)), ((), ())),
+                x_int[:, k0:k0 + block_k],
+                planes_ref[0, p, k0:k0 + block_k, :],
+                (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.int32)
         acc = acc + (part << (cell_bits * p))
     return acc - (row_sums << (weight_bits - 1))
@@ -118,7 +134,7 @@ def _dequant_tile(y_int, s, sw_ref, bias_ref, mask_ref, l, i, *,
     round-trip through HBM), then zero the padded rows/columns exactly as
     the sequential path's slice-to-real-shape does — col_mask at tile
     granularity handles real widths that end mid-tile."""
-    y = y_int.astype(jnp.float32) * (s * sw_ref[0, 0]) + bias_ref[...]
+    y = y_int.astype(jnp.float32) * (s * sw_ref[l]) + bias_ref[...]
     do_relu = jnp.logical_or(l < n_layers - 1, final_relu)
     y = jnp.where(do_relu, jnp.maximum(y, 0.0), y)
     y = y * mask_ref[...]
@@ -127,14 +143,22 @@ def _dequant_tile(y_int, s, sw_ref, bias_ref, mask_ref, l, i, *,
     return jnp.where(row_ids < m_real, y, 0.0)
 
 
-def _finalize_layer_scale(s_ref, mx_ref, sx0_ref, l, qmax: float):
+def _layer_scale(mx, qmax: float):
+    """A layer's input scale from max|prev layer output| (the
+    ``quantize_tensor`` formula, run inside the kernel)."""
+    return jnp.maximum(mx / qmax, 1e-12)
+
+
+def _requant(act, s, qmax: float):
+    return jnp.clip(jnp.round(act / s), -qmax, qmax).astype(jnp.int32)
+
+
+def _finalize_layer_scale(s_ref, mx_ref, sx0_ref, b, l, qmax: float):
     """At each (batch element, layer)'s first tile: finalize this layer's
     global input scale — the element's external quant scale for layer 0,
     else max|prev layer output| / qmax (``quantize_tensor`` semantics) —
     and zero the running max that accumulates the NEXT layer's scale."""
-    s_ref[0] = jnp.where(
-        l == 0, sx0_ref[0, 0],
-        jnp.maximum(mx_ref[0] / qmax, 1e-12))
+    s_ref[0] = jnp.where(l == 0, sx0_ref[b], _layer_scale(mx_ref[0], qmax))
     mx_ref[0] = jnp.float32(0)
 
 
@@ -143,8 +167,8 @@ def _requant_stripe(act_stripe, x0_ref, s, l, qmax: float):
     later N-tiles must not re-read rows whose low columns the first
     N-tile already overwrote with this layer's outputs. Layer 0 takes
     the pre-quantized ints instead."""
-    x_q = jnp.clip(jnp.round(act_stripe / s), -qmax, qmax).astype(jnp.int32)
-    return jnp.where(l == 0, x0_ref[0].astype(jnp.int32), x_q)
+    return jnp.where(l == 0, x0_ref[0].astype(jnp.int32),
+                     _requant(act_stripe, s, qmax))
 
 
 # ---------------------------------------------------------------------------
@@ -155,6 +179,7 @@ def _kernel(x0_ref, planes_ref, bias_ref, sw_ref, sx0_ref, mask_ref,
             o_ref, act_ref, xq_ref, xs_ref, s_ref, mx_ref, *,
             n_layers: int, n_planes: int, cell_bits: int, weight_bits: int,
             block_m: int, block_k: int, m_real: int, final_relu: bool):
+    b = pl.program_id(0)
     l = pl.program_id(1)            # layer (sequential, after batch)
     i = pl.program_id(2)            # activation stripe
     j = pl.program_id(3)            # output N-tile (innermost)
@@ -162,17 +187,17 @@ def _kernel(x0_ref, planes_ref, bias_ref, sw_ref, sx0_ref, mask_ref,
 
     @pl.when(jnp.logical_and(i == 0, j == 0))
     def _start_layer():
-        _finalize_layer_scale(s_ref, mx_ref, sx0_ref, l, qmax)
+        _finalize_layer_scale(s_ref, mx_ref, sx0_ref, b, l, qmax)
 
     s = s_ref[0]
-    rows = pl.ds(i * block_m, block_m)
+    rows = pl.ds(pl.multiple_of(i * block_m, block_m), block_m)
 
     @pl.when(j == 0)
     def _snapshot_input():
         # the offset-correction row sums only depend on (l, i) too, so they
         # are reduced here once instead of per N-tile
         x_new = _requant_stripe(act_ref[rows, :], x0_ref, s, l, qmax)
-        xq_ref[...] = x_new
+        xq_ref[...] = x_new.astype(jnp.int8)
         xs_ref[...] = jnp.sum(x_new, axis=1, keepdims=True)
 
     x_int = xq_ref[...]
@@ -208,11 +233,11 @@ def _kernel_mtiled(x0_ref, planes_ref, bias_ref, sw_ref, sx0_ref, mask_ref,
     j = pl.program_id(3)            # output N-tile (innermost)
     n_steps = pl.num_programs(3)
     qmax = float(2 ** (weight_bits - 1) - 1)
-    rows = pl.ds(i * block_m, block_m)
+    rows = pl.ds(pl.multiple_of(i * block_m, block_m), block_m)
 
     @pl.when(jnp.logical_and(i == 0, j == 0))
     def _start_layer():
-        _finalize_layer_scale(s_ref, mx_ref, sx0_ref, l, qmax)
+        _finalize_layer_scale(s_ref, mx_ref, sx0_ref, b, l, qmax)
 
     s = s_ref[0]
 
@@ -229,7 +254,7 @@ def _kernel_mtiled(x0_ref, planes_ref, bias_ref, sw_ref, sx0_ref, mask_ref,
             cin.start()
             cin.wait()
         x_new = _requant_stripe(stripe_ref[...], x0_ref, s, l, qmax)
-        xq_ref[...] = x_new
+        xq_ref[...] = x_new.astype(jnp.int8)
         xs_ref[...] = jnp.sum(x_new, axis=1, keepdims=True)
 
     x_int = xq_ref[...]
@@ -242,7 +267,7 @@ def _kernel_mtiled(x0_ref, planes_ref, bias_ref, sw_ref, sx0_ref, mask_ref,
                       final_relu=final_relu)
 
     mx_ref[0] = jnp.maximum(mx_ref[0], jnp.max(jnp.abs(y)))
-    # the int32 snapshot already decoupled reads from writes, so the f32
+    # the int8 snapshot already decoupled reads from writes, so the f32
     # stripe buffer is dead after _stage_stripe and collects the outputs
     stripe_ref[:, pl.ds(j * bn, bn)] = y
 
@@ -264,15 +289,16 @@ def _kernel_wstat(x0_ref, planes_ref, bias_ref, sw_ref, sx0_ref, mask_ref,
                   n_layers: int, n_planes: int, cell_bits: int,
                   weight_bits: int, block_m: int, block_k: int, m_real: int,
                   final_relu: bool):
+    b = pl.program_id(0)
     l = pl.program_id(1)
     j = pl.program_id(2)            # output N-tile (OUTERMOST of the sweep)
     i = pl.program_id(3)            # activation stripe (innermost)
     qmax = float(2 ** (weight_bits - 1) - 1)
-    rows = pl.ds(i * block_m, block_m)
+    rows = pl.ds(pl.multiple_of(i * block_m, block_m), block_m)
 
     @pl.when(jnp.logical_and(j == 0, i == 0))
     def _start_layer():
-        _finalize_layer_scale(s_ref, mx_ref, sx0_ref, l, qmax)
+        _finalize_layer_scale(s_ref, mx_ref, sx0_ref, b, l, qmax)
 
     s = s_ref[0]
 
@@ -285,7 +311,7 @@ def _kernel_wstat(x0_ref, planes_ref, bias_ref, sw_ref, sx0_ref, mask_ref,
         xq_ref[rows, :] = x_new.astype(jnp.int8)
         xs_ref[rows, :] = jnp.sum(x_new, axis=1, keepdims=True)
 
-    x_int = xq_ref[rows, :].astype(jnp.int32)
+    x_int = xq_ref[rows, :]
     bn = planes_ref.shape[-1]
     y_int = _plane_matmul(x_int, planes_ref, xs_ref[rows, :],
                           n_planes=n_planes, cell_bits=cell_bits,
@@ -307,11 +333,13 @@ def _kernel_wstat(x0_ref, planes_ref, bias_ref, sw_ref, sx0_ref, mask_ref,
 # ---------------------------------------------------------------------------
 
 def _launch(x_p, sx, program: CrossbarProgram, *, mode: str, m_real: int,
-            final_relu: bool, block_m: int, block_n: int, block_k: int,
-            interpret: bool):
+            final_relu: bool, block_m: int, block_n: int, block_k: int):
     """One ``pallas_call`` over pre-quantized ``(B, m_pad, d)`` int8 rows
     with per-batch-element scales ``sx`` of shape ``(B, 1)``, under the
-    ``mode`` dataflow (see module docstring)."""
+    ``mode`` dataflow (see module docstring). The per-layer bias and
+    col-mask rows ride a unit axis, ``(L, 1, d)`` with ``(1, block_n)``
+    blocks: Mosaic accepts a block dim that is not a multiple of the
+    8-sublane tile only when it spans the whole array dim."""
     b, m_pad, d = x_p.shape
     m_steps = m_pad // block_m
     n_steps = d // block_n
@@ -322,8 +350,9 @@ def _launch(x_p, sx, program: CrossbarProgram, *, mode: str, m_real: int,
                   weight_bits=program.weight_bits,
                   block_m=block_m, block_k=block_k, m_real=m_real,
                   final_relu=final_relu)
-    operands = (x_p, program.planes, program.bias, program.w_scale, sx,
-                program.col_mask)
+    operands = (x_p, program.planes, program.bias[:, None, :],
+                program.w_scale.reshape(-1), sx.reshape(-1),
+                program.col_mask[:, None, :])
     out_shape = jax.ShapeDtypeStruct((b, m_pad, d), jnp.float32)
 
     if mode == "wstat":
@@ -336,12 +365,12 @@ def _launch(x_p, sx, program: CrossbarProgram, *, mode: str, m_real: int,
                              lambda bb, l, j, i: (bb, i, 0)),
                 pl.BlockSpec((1, n_planes, d, block_n),
                              lambda bb, l, j, i: (l, 0, 0, j)),
-                pl.BlockSpec((1, block_n), lambda bb, l, j, i: (l, j)),
-                pl.BlockSpec((1, 1), lambda bb, l, j, i: (l, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, 1), lambda bb, l, j, i: (bb, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, block_n), lambda bb, l, j, i: (l, j)),
+                pl.BlockSpec((None, 1, block_n),
+                             lambda bb, l, j, i: (l, 0, j)),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((None, 1, block_n),
+                             lambda bb, l, j, i: (l, 0, j)),
             ],
             out_specs=pl.BlockSpec((1, block_m, block_n),
                                    lambda bb, l, j, i: (bb, i, j)),
@@ -353,7 +382,8 @@ def _launch(x_p, sx, program: CrossbarProgram, *, mode: str, m_real: int,
                 pltpu.SMEM((1,), jnp.float32),        # current layer scale
                 pltpu.SMEM((1,), jnp.float32),        # running max|output|
             ],
-            interpret=interpret,
+            compiler_params=_COMPILER_PARAMS,
+            interpret=interpret_mode(),
         )(*operands)
 
     if mode == "mtiled":
@@ -366,26 +396,27 @@ def _launch(x_p, sx, program: CrossbarProgram, *, mode: str, m_real: int,
                              lambda bb, l, i, j: (bb, i, 0)),
                 pl.BlockSpec((1, n_planes, d, block_n),
                              lambda bb, l, i, j: (l, 0, 0, j)),
-                pl.BlockSpec((1, block_n), lambda bb, l, i, j: (l, j)),
-                pl.BlockSpec((1, 1), lambda bb, l, i, j: (l, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, 1), lambda bb, l, i, j: (bb, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, block_n), lambda bb, l, i, j: (l, j)),
+                pl.BlockSpec((None, 1, block_n),
+                             lambda bb, l, i, j: (l, 0, j)),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((None, 1, block_n),
+                             lambda bb, l, i, j: (l, 0, j)),
             ],
             # the output stays in HBM and doubles as the activation panel;
             # the kernel DMAs stripes in/out itself
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             out_shape=out_shape,
             scratch_shapes=[
                 pltpu.VMEM((block_m, d), jnp.float32),  # DMA-staged stripe
-                pltpu.VMEM((block_m, d), jnp.int32),    # stripe snapshot
+                pltpu.VMEM((block_m, d), jnp.int8),     # stripe snapshot
                 pltpu.VMEM((block_m, 1), jnp.int32),    # stripe row sums
                 pltpu.SMEM((1,), jnp.float32),          # current layer scale
                 pltpu.SMEM((1,), jnp.float32),          # running max|output|
                 pltpu.SemaphoreType.DMA,                # stripe DMA sem
             ],
-            interpret=interpret,
+            compiler_params=_COMPILER_PARAMS,
+            interpret=interpret_mode(),
         )(*operands)
 
     return pl.pallas_call(
@@ -396,24 +427,25 @@ def _launch(x_p, sx, program: CrossbarProgram, *, mode: str, m_real: int,
             pl.BlockSpec((1, block_m, d), lambda bb, l, i, j: (bb, i, 0)),
             pl.BlockSpec((1, n_planes, d, block_n),
                          lambda bb, l, i, j: (l, 0, 0, j)),
-            pl.BlockSpec((1, block_n), lambda bb, l, i, j: (l, j)),
-            pl.BlockSpec((1, 1), lambda bb, l, i, j: (l, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda bb, l, i, j: (bb, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_n), lambda bb, l, i, j: (l, j)),
+            pl.BlockSpec((None, 1, block_n),
+                         lambda bb, l, i, j: (l, 0, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, 1, block_n),
+                         lambda bb, l, i, j: (l, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, block_m, block_n),
                                lambda bb, l, i, j: (bb, i, j)),
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((m_pad, d), jnp.float32),   # inter-layer activations
-            pltpu.VMEM((block_m, d), jnp.int32),   # input-stripe snapshot
+            pltpu.VMEM((block_m, d), jnp.int8),    # input-stripe snapshot
             pltpu.VMEM((block_m, 1), jnp.int32),   # stripe row sums (offset)
             pltpu.SMEM((1,), jnp.float32),         # current layer act scale
             pltpu.SMEM((1,), jnp.float32),         # running max|output|
         ],
-        interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret_mode(),
     )(*operands)
 
 
@@ -426,15 +458,13 @@ def _check_bits(program: CrossbarProgram):
 
 
 @functools.partial(jax.jit, static_argnames=("final_relu", "mode", "block_m",
-                                             "block_n", "block_k",
-                                             "interpret"))
+                                             "block_n", "block_k"))
 def reram_mlp_fused(x: jnp.ndarray, program: CrossbarProgram, *,
                     final_relu: bool = True,
                     mode: str | None = None,
                     block_m: int = DEFAULT_BLOCK_M,
                     block_n: int | None = None,
-                    block_k: int | None = None,
-                    interpret: bool = True) -> jnp.ndarray:
+                    block_k: int | None = None) -> jnp.ndarray:
     """Float ``(…, d0)`` through the whole programmed MLP -> ``(…, dL)``,
     in a single ``pallas_call``. Same quantization scales and exact same
     integer arithmetic as chaining ``reram_linear`` + bias + ReLU per layer
@@ -457,20 +487,18 @@ def reram_mlp_fused(x: jnp.ndarray, program: CrossbarProgram, *,
     out = _launch(x_p, sx.reshape(1, 1).astype(jnp.float32), program,
                   mode=plan.mode, m_real=m0, final_relu=final_relu,
                   block_m=plan.block_m, block_n=plan.block_n,
-                  block_k=plan.block_k, interpret=interpret)
+                  block_k=plan.block_k)
     return out[0, :m0, :widths[-1]].reshape(*lead, widths[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("final_relu", "mode", "block_m",
-                                             "block_n", "block_k",
-                                             "interpret"))
+                                             "block_n", "block_k"))
 def reram_mlp_fused_batched(x: jnp.ndarray, program: CrossbarProgram, *,
                             final_relu: bool = True,
                             mode: str | None = None,
                             block_m: int = DEFAULT_BLOCK_M,
                             block_n: int | None = None,
-                            block_k: int | None = None,
-                            interpret: bool = True) -> jnp.ndarray:
+                            block_k: int | None = None) -> jnp.ndarray:
     """Float ``(B, …, d0)`` -> ``(B, …, dL)`` with the batch folded into
     the kernel grid: ONE ``pallas_call`` for the whole batch, no outer
     vmap. Each batch element keeps its own input quantization scale and
@@ -495,5 +523,69 @@ def reram_mlp_fused_batched(x: jnp.ndarray, program: CrossbarProgram, *,
     out = _launch(x_p, sx.reshape(batch, 1).astype(jnp.float32), program,
                   mode=plan.mode, m_real=m0, final_relu=final_relu,
                   block_m=plan.block_m, block_n=plan.block_n,
-                  block_k=plan.block_k, interpret=interpret)
+                  block_k=plan.block_k)
     return out[:, :m0, :widths[-1]].reshape(batch, *lead, widths[-1])
+
+
+# ---------------------------------------------------------------------------
+# the inter-layer requantizer alone
+# ---------------------------------------------------------------------------
+
+def _requant_kernel(mx_ref, y_ref, q_ref, s_ref, *, qmax: float):
+    s_ref[0] = _layer_scale(mx_ref[0], qmax)
+    q_ref[...] = _requant(y_ref[...], s_ref[0], qmax)
+
+
+@jax.jit
+def requantize(y: jnp.ndarray):
+    """The fused kernel's inter-layer requantizer as a ``pallas_call`` of
+    its own: float ``(m, n)`` -> ``(int32 values, scale)`` through the same
+    scale and rounding ops the kernel applies between two layers (INT8).
+    Where f32 division is correctly rounded this is ``quantize_tensor``
+    bitwise; where it is not (a TPU), this gives what the fused kernel
+    computes there, so an oracle can take the device's own quotients."""
+    m, n = y.shape
+    block_m = min(DEFAULT_BLOCK_M, _ceil_to(m, 8))
+    m_pad = _ceil_to(m, block_m)
+    n_pad = _ceil_to(n, CROSSBAR)
+    y_p = jnp.zeros((m_pad, n_pad), jnp.float32).at[:m, :n].set(y)
+    mx = jnp.max(jnp.abs(y)).reshape(1).astype(jnp.float32)
+    q, s = pl.pallas_call(
+        functools.partial(_requant_kernel, qmax=127.0),
+        name="requantize",
+        grid=(m_pad // block_m,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec((block_m, n_pad), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((block_m, n_pad), lambda i: (i, 0)),
+                   pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_shape=[jax.ShapeDtypeStruct((m_pad, n_pad), jnp.int32),
+                   jax.ShapeDtypeStruct((1,), jnp.float32)],
+        interpret=interpret_mode(),
+    )(mx, y_p)
+    return q[:m, :n], s[0]
+
+
+def chain_quantizer(program: CrossbarProgram):
+    """A ``quantize`` hook for :func:`repro.kernels.ref.quant_chain_np`
+    that takes the program's arithmetic from the current device: the
+    programmed integer weights and scales, ``quantize_tensor`` on the
+    first layer's input (as the fused wrappers run it) and
+    :func:`requantize` between layers. On a device whose f32 division is
+    not correctly rounded, the oracle then differs from the fused kernel
+    only where the rest of the kernel's arithmetic does."""
+    widths = program.widths
+    weights = [
+        (np.asarray(combine_planes(program.planes[l], program.cell_bits,
+                                   program.weight_bits))[:k, :n],
+         np.float32(program.w_scale[l, 0]))
+        for l, (k, n) in enumerate(zip(widths[:-1], widths[1:]))]
+    first = jax.jit(functools.partial(quantize_tensor,
+                                      bits=program.weight_bits))
+
+    def quantize(v, i, operand):
+        if operand == "w":
+            return weights[i]
+        q, s = (first if i == 0 else requantize)(jnp.asarray(v))
+        return np.asarray(q), np.float32(s)
+
+    return quantize

@@ -24,14 +24,10 @@ from .reram_mlp import reram_matmul_int
 from .ref import combine_planes
 
 __all__ = [
-    "on_tpu", "encode_planes", "quantize_tensor", "reram_linear",
+    "encode_planes", "quantize_tensor", "reram_linear",
     "aggregate_diff", "aggregate_diff_batched", "fps_update",
     "fps", "count_dma_elisions",
 ]
-
-
-def on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
 
 
 def _pad_to(x, axis, mult):
@@ -43,10 +39,9 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, widths)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "fault_model"))
+@functools.partial(jax.jit, static_argnames=("fault_model",))
 def reram_linear(x: jnp.ndarray, w: jnp.ndarray,
-                 b: jnp.ndarray | None = None, *,
-                 interpret: bool = True, fault_model=None,
+                 b: jnp.ndarray | None = None, *, fault_model=None,
                  fault_key: jnp.ndarray | None = None) -> jnp.ndarray:
     """Float (…, K) @ (K, N) through the bit-sliced crossbar kernel.
 
@@ -69,15 +64,15 @@ def reram_linear(x: jnp.ndarray, w: jnp.ndarray,
     m0 = x2.shape[0]
     x_p = _pad_to(_pad_to(x_int.astype(jnp.int8), 0, 128), 1, 128)
     planes_p = _pad_to(_pad_to(planes, 1, 128), 2, 128)
-    out = reram_matmul_int(x_p, planes_p, interpret=interpret)
+    out = reram_matmul_int(x_p, planes_p)
     out = out[:m0, :n].astype(jnp.float32) * (sx * sw)
     if b is not None:
         out = out + b
     return out.reshape(*lead, n)
 
 
-def fps(points: jnp.ndarray, n_samples: int, *, start: int = 0,
-        interpret: bool = True) -> jnp.ndarray:
+def fps(points: jnp.ndarray, n_samples: int, *,
+        start: int = 0) -> jnp.ndarray:
     """Full farthest-point sampling driven by the ``fps_update`` kernel."""
     n = points.shape[0]
     pts_t = _pad_to(points.T, 1, 128)               # (3, N_pad)
@@ -88,7 +83,7 @@ def fps(points: jnp.ndarray, n_samples: int, *, start: int = 0,
         idx, dist, cur = state
         idx = idx.at[i].set(cur)
         c = jax.lax.dynamic_slice(pts_t, (0, cur), (3, 1))
-        dist = fps_update(pts_t, c, dist, interpret=interpret)
+        dist = fps_update(pts_t, c, dist)
         dist = jnp.where(valid, dist, -jnp.inf)
         return idx, dist, jnp.argmax(dist[0]).astype(jnp.int32)
 

@@ -304,7 +304,7 @@ def fused_vmem_bytes(d_pad: int, n_planes: int, m_pad: int,
         )                               # (output is HBM-resident, no block)
         scratch = (
             4 * block_m * d_pad         # f32 DMA-staged activation stripe
-            + 4 * block_m * d_pad       # int32 requantized-stripe snapshot
+            + block_m * d_pad           # int8 requantized-stripe snapshot
             + 4 * block_m               # int32 stripe row sums
         )
         return 2 * blocks + scratch
@@ -323,7 +323,7 @@ def fused_vmem_bytes(d_pad: int, n_planes: int, m_pad: int,
     else:                               # whole / tiled
         scratch = (
             4 * m_pad * d_pad           # f32 inter-layer activation panel
-            + 4 * block_m * d_pad       # int32 requantized-stripe snapshot
+            + block_m * d_pad           # int8 requantized-stripe snapshot
             + 4 * block_m               # int32 stripe row sums
         )
     return 2 * blocks + scratch

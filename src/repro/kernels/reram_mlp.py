@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import interpret_mode
+
 __all__ = ["reram_matmul_int"]
 
 DEFAULT_BLOCK = (128, 128, 128)   # (bm, bn, bk) = the crossbar geometry
@@ -42,13 +44,14 @@ def _kernel(x_ref, planes_ref, o_ref, acc_ref, xsum_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         xsum_ref[...] = jnp.zeros_like(xsum_ref)
 
-    x = x_ref[...].astype(jnp.int32)                      # (bm, bk)
-    xsum_ref[...] += jnp.sum(x, axis=1, keepdims=True)
+    x = x_ref[...]                                        # (bm, bk) int8
+    xsum_ref[...] += jnp.sum(x.astype(jnp.int32), axis=1, keepdims=True)
     acc = acc_ref[...]
     for p in range(n_planes):                             # 4 cell planes
-        w = planes_ref[p].astype(jnp.int32)               # (bk, bn)
+        # int8 x int8 -> int32 is the MXU's integer mode (Mosaic has no
+        # int32 x int32 matmul); the cells are 0..3, so they fit int8
         part = jax.lax.dot_general(
-            x, w, (((1,), (0,)), ((), ())),
+            x, planes_ref[p], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
         acc = acc + (part << (cell_bits * p))
     acc_ref[...] = acc
@@ -60,12 +63,12 @@ def _kernel(x_ref, planes_ref, o_ref, acc_ref, xsum_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "cell_bits", "weight_bits", "block", "interpret"))
+    "cell_bits", "weight_bits", "block"))
 def reram_matmul_int(x_int: jnp.ndarray, planes: jnp.ndarray, *,
                      cell_bits: int = 2, weight_bits: int = 8,
-                     block: tuple[int, int, int] = DEFAULT_BLOCK,
-                     interpret: bool = True) -> jnp.ndarray:
-    """``x_int`` (M, K) int8/int32 activations; ``planes`` (P, K, N) int8
+                     block: tuple[int, int, int] = DEFAULT_BLOCK
+                     ) -> jnp.ndarray:
+    """``x_int`` (M, K) int8 activations; ``planes`` (P, K, N) int8
     offset-binary 2-bit planes (LSB first). Returns (M, N) int32 equal to
     ``x_int @ (combine(planes) - 2**(weight_bits-1))``."""
     m, kdim = x_int.shape
@@ -94,5 +97,5 @@ def reram_matmul_int(x_int: jnp.ndarray, planes: jnp.ndarray, *,
             pltpu.VMEM((bm, bn), jnp.int32),
             pltpu.VMEM((bm, 1), jnp.int32),
         ],
-        interpret=interpret,
-    )(x_int, planes)
+        interpret=interpret_mode(),
+    )(x_int.astype(jnp.int8), planes)

@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ._compat import axis_size, pvary
-
 __all__ = ["compressed_psum", "overlapped_tp_matmul"]
 
 
@@ -53,7 +51,7 @@ def overlapped_tp_matmul(x_shard: jnp.ndarray, w_shard: jnp.ndarray,
     column-sharded weights: x_shard (m/N, k), w_shard (k, n/N) would use
     psum; here we do the all-gather form used before a row-parallel matmul.
     """
-    n_dev = axis_size(axis_name)
+    n_dev = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
 
@@ -70,6 +68,6 @@ def overlapped_tp_matmul(x_shard: jnp.ndarray, w_shard: jnp.ndarray,
     acc0 = jnp.zeros((x_shard.shape[0], w_shard.shape[-1]),
                      jnp.promote_types(x_shard.dtype, w_shard.dtype))
     # the accumulator becomes device-varying once shards rotate in
-    acc0 = pvary(acc0, (axis_name,))
+    acc0 = lax.pcast(acc0, (axis_name,), to="varying")
     acc, _, _ = lax.fori_loop(0, n_dev, body, (acc0, x_shard, idx))
     return acc

@@ -15,11 +15,21 @@ with shape (N, 16, 16).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_test_mesh", "make_replica_mesh",
            "batch_axes", "MESH_AXES"]
 
 MESH_AXES = {"single": ("data", "model"), "multi": ("pod", "data", "model")}
+
+
+def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: operands carry their
+    sharding, ``jit`` propagates it and ``with mesh:`` scopes the
+    partitioner. (``make_mesh`` defaults to ``Explicit`` axes, which type
+    every intermediate's sharding and reject ops such as an embedding
+    gather whose output sharding is ambiguous.)"""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_replica_mesh(n_replicas: int | None = None, *,
@@ -31,19 +41,19 @@ def make_replica_mesh(n_replicas: int | None = None, *,
     shard_batch``) — so this is the entire mesh story for serving, unlike
     the LM's (data, model) factorization."""
     n = len(jax.devices()) if n_replicas is None else int(n_replicas)
-    return jax.make_mesh((n,), (axis,))
+    return _auto_mesh((n,), (axis,))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")) -> jax.sharding.Mesh:
     """Small mesh for unit tests (requires xla_force_host_platform_device_count
     set in the test's subprocess)."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def batch_axes(mesh: jax.sharding.Mesh):

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ._compat import axis_size, pvary
-
 __all__ = ["pipeline_forward"]
 
 
@@ -33,7 +31,7 @@ def pipeline_forward(stage_fn, stage_params, x_mb, *, axis_name: str):
                    (only stage 0 consumes it)
     returns      : (M, mb, ...) outputs valid on the LAST stage.
     """
-    s = axis_size(axis_name)
+    s = lax.axis_size(axis_name)
     sid = lax.axis_index(axis_name)
     m = x_mb.shape[0]
     t_total = m + s - 1
@@ -56,8 +54,8 @@ def pipeline_forward(stage_fn, stage_params, x_mb, *, axis_name: str):
         return buf, out
 
     # loop carries become device-varying after the first ppermute/select
-    buf0 = pvary(jnp.zeros_like(x_mb[0]), (axis_name,))
-    out0 = pvary(jnp.zeros_like(x_mb), (axis_name,))
+    buf0 = lax.pcast(jnp.zeros_like(x_mb[0]), (axis_name,), to="varying")
+    out0 = lax.pcast(jnp.zeros_like(x_mb), (axis_name,), to="varying")
     _, out = lax.fori_loop(0, t_total, step, (buf0, out0))
     # broadcast the last stage's result so the output is replicated
     return lax.psum(jnp.where(sid == s - 1, out, 0), axis_name)

@@ -396,14 +396,16 @@ class PointCloudServable(Servable):
     jittable (the plan becomes a device operand instead of a host loop).
 
     ``mesh`` (a 1-D replica mesh from
-    :func:`repro.launch.mesh.make_replica_mesh`) shards the batch
-    dimension of every operand across replicas before the jitted step;
-    jit follows the operand sharding, so each replica runs its slice of
-    the batch. Batch buckets should be multiples of the replica count —
-    non-divisible batches fall back to replicated (correct, not faster) —
-    and at least 2x it for bitwise-equal results: a lone cloud per replica
-    is the singleton-batch case again (XLA collapses the local unit batch
-    dim and re-fuses the float matmuls).
+    :func:`repro.launch.mesh.make_replica_mesh`) runs the step once per
+    replica under ``shard_map``: the batch is padded up to a multiple of
+    the replica count, its operands are sharded over the replica axis,
+    and every replica runs the whole ``batched_forward`` — Pallas
+    kernels included — on its own slice. (A ``pallas_call`` is a custom
+    call that GSPMD cannot partition, so the step is never left to it.)
+    Batch buckets of at least 2x the replica count keep float results
+    bitwise-equal: a lone cloud per replica is the singleton-batch case
+    again (XLA collapses the local unit batch dim and re-fuses the float
+    matmuls).
     """
 
     def __init__(self, model, *, buckets: ShapeBuckets | None = None,
@@ -446,7 +448,24 @@ class PointCloudServable(Servable):
         self.batches = 0
         self.jit_traces = 0
         self.trace_shapes: list[tuple[int, int]] = []
-        self._jit_step = jax.jit(self._step)
+        # the host-planning fallback (planned model, cache off, no traced
+        # plan construction) cannot live under jit — everything else runs
+        # through the ONE cached jitted step per bucket shape
+        self._jittable = (self.plan_cache is not None or not model.planned
+                          or model.device_planning
+                          or model.device_plan is not None)
+        step = self._step
+        if mesh is not None:
+            if not self._jittable:
+                raise ValueError(
+                    "mesh= needs a jittable serving step; this model plans "
+                    "on host per cloud with the plan cache off")
+            (axis,) = mesh.axis_names
+            step = jax.shard_map(step, mesh=mesh,
+                                 in_specs=jax.sharding.PartitionSpec(axis),
+                                 out_specs=jax.sharding.PartitionSpec(axis),
+                                 check_vma=False)
+        self._jit_step = jax.jit(step)
         # cache misses build the plan OUTSIDE the serving step; for
         # device-planning models the whole build (masked FPS/kNN +
         # Algorithm 1) is traceable, so compile it once per point bucket —
@@ -498,6 +517,9 @@ class PointCloudServable(Servable):
             # eager forward; one replicated row keeps the vmapped program
             # intact at negligible cost in the latency-bound regime
             b_bucket = 2
+        if self.mesh is not None:                # whole slices per replica
+            n_rep = self.mesh.devices.size
+            b_bucket = -(-b_bucket // n_rep) * n_rep
         padded = np.zeros((b_bucket, n_bucket, 3), np.float32)
         n_valid = np.empty((b_bucket,), np.int32)
         for i, c in enumerate(clouds):
@@ -515,17 +537,7 @@ class PointCloudServable(Servable):
 
         clouds_d = jnp.asarray(padded)
         nv_d = jnp.asarray(n_valid)
-        if self.mesh is not None:
-            from repro.launch.sharding import shard_batch
-            clouds_d, nv_d, dplan = shard_batch(
-                (clouds_d, nv_d, dplan), self.mesh)
-        # the host-planning fallback (planned model, cache off, no traced
-        # plan construction) cannot live under jit — everything else runs
-        # through the ONE cached jitted step per bucket shape
-        jittable = (dplan is not None or not self.model.planned
-                    or self.model.device_planning
-                    or self.model.device_plan is not None)
-        if jittable:
+        if self._jittable:
             logits = self._jit_step(clouds_d, nv_d, dplan)
         else:
             logits = self.model.batched_forward(clouds_d, n_valid=nv_d)

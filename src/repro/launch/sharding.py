@@ -204,8 +204,8 @@ def replica_pspecs(tree, mesh: Mesh, *, axis: str = "replica"):
 def shard_batch(tree, mesh: Mesh, *, axis: str = "replica"):
     """``device_put`` a pytree of batch operands with
     :func:`replica_pspecs` shardings — the serving engine calls this on
-    (clouds, n_valid, dplan) before its jitted step; jit then follows the
-    operand sharding and each replica computes its batch slice."""
+    (clouds, n_valid, dplan) before its per-replica ``shard_map`` step, so
+    each replica's slice is already on its device."""
     specs = replica_pspecs(tree, mesh, axis=axis)
     return jax.tree.map(
         lambda leaf, s: jax.device_put(leaf, NamedSharding(mesh, s)),

@@ -216,12 +216,8 @@ class ReramPerLayerBackend(FloatBackend):
     deterministic across calls. The zero-fault model takes the ideal path
     bit-for-bit."""
 
-    def __init__(self, params, config, *, interpret: bool = True,
-                 fault_model=None):
-        super().__init__(
-            params, config,
-            matmul=lambda a, w: reram_linear(a, w, interpret=interpret))
-        self.interpret = interpret
+    def __init__(self, params, config, *, fault_model=None):
+        super().__init__(params, config, matmul=reram_linear)
         self.fault_model = fault_model
 
     def apply_mlp(self, key, x, *, final_relu=True):
@@ -234,7 +230,7 @@ class ReramPerLayerBackend(FloatBackend):
         mlp_ix = 0 if key == "head" else key[1] + 1
         layer_ix = itertools.count()
         mm = lambda a, w: reram_linear(
-            a, w, interpret=self.interpret, fault_model=fm,
+            a, w, fault_model=fm,
             fault_key=fm.key_for(mlp_ix, next(layer_ix)))
         return _pn._apply_mlp(self._mlp_params(key), x,
                               final_relu=final_relu, matmul=mm)
@@ -259,7 +255,7 @@ class ReramFusedBackend(Backend):
     def __init__(self, params, config, *, program=None,
                  mode: str | None = None,
                  block_n: int | None = None, block_k: int | None = None,
-                 interpret: bool = True, ecc=None, fault_model=None):
+                 ecc=None, fault_model=None):
         super().__init__(params, config)
         if program is None:
             program = _pn.build_model_program(params, ecc=ecc)
@@ -281,7 +277,6 @@ class ReramFusedBackend(Backend):
         self.mode = mode if mode is not None else type(self).mode
         self.block_n = block_n
         self.block_k = block_k
-        self.interpret = interpret
         self._plan_cache: dict = {}
 
     def _prog(self, key):
@@ -306,16 +301,14 @@ class ReramFusedBackend(Backend):
         fp = self._fused_plan(key, int(np.prod(x.shape[:-1], dtype=np.int64)))
         return reram_mlp_fused(x, self._prog(key), final_relu=final_relu,
                                mode=fp.mode, block_n=fp.block_n,
-                               block_k=fp.block_k,
-                               interpret=self.interpret)
+                               block_k=fp.block_k)
 
     def apply_mlp_batched(self, key, x, *, final_relu=True):
         fp = self._fused_plan(key,
                               int(np.prod(x.shape[1:-1], dtype=np.int64)))
         return reram_mlp_fused_batched(
             x, self._prog(key), final_relu=final_relu, mode=fp.mode,
-            block_n=fp.block_n, block_k=fp.block_k,
-            interpret=self.interpret)
+            block_n=fp.block_n, block_k=fp.block_k)
 
     def stats(self) -> dict:
         progs = {f"sa{i}": p for i, p in enumerate(self.program["sa"])}

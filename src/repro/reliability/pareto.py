@@ -77,8 +77,7 @@ def sweep(params, config: PointNetConfig, *,
           design: str = "pointer",
           hw: HWParams = DEFAULT_HW,
           ecc_group: int = 16,
-          n_classes: int = 40,
-          interpret: bool = True) -> list[DesignPoint]:
+          n_classes: int = 40) -> list[DesignPoint]:
     """Run the fault-rate x protection grid and score every point.
 
     One ideal reference model is compiled once; each grid point compiles
@@ -97,8 +96,7 @@ def sweep(params, config: PointNetConfig, *,
                                           n_points=config.n_points,
                                           seed=seed + i))
               for i in range(n_clouds)]
-    ideal = compile_model(params, config, backend=backend,
-                          interpret=interpret)
+    ideal = compile_model(params, config, backend=backend)
     ref = [int(np.argmax(np.asarray(ideal.forward(c)))) for c in clouds]
 
     from repro.core.simulator import run_design  # deferred: layering
@@ -116,16 +114,14 @@ def sweep(params, config: PointNetConfig, *,
         surcharge, extra_arrays = 0.0, 0
         if ecc is not None:
             # overheads depend only on the program layout, not the faults
-            probe = compile_model(params, config, backend=backend,
-                                  interpret=interpret, ecc=ecc)
+            probe = compile_model(params, config, backend=backend, ecc=ecc)
             rel = probe.stats()["reliability"]["ecc"]
             surcharge, extra_arrays = (rel["scrub_energy_j"],
                                        rel["extra_arrays"])
         for rate in fault_rates:
             fm = _fault_model(rate, seed)
             model = compile_model(params, config, backend=backend,
-                                  interpret=interpret, ecc=ecc,
-                                  fault_model=fm)
+                                  ecc=ecc, fault_model=fm)
             agree = sum(
                 int(np.argmax(np.asarray(model.forward(c)))) == r
                 for c, r in zip(clouds, ref))

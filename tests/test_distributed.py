@@ -32,7 +32,8 @@ from repro.launch import sharding as shd
 from repro.launch.train import init_train_state, make_train_step
 from repro.optim import AdamWConfig
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_test_mesh
+mesh = make_test_mesh((4, 2), ("data", "model"))
 cfg = dataclasses.replace(get_config("deepseek-7b").reduced(),
                           batch_axes=("data",), tp=2)
 opt = AdamWConfig()
@@ -70,9 +71,10 @@ def test_pipeline_forward_matches_sequential():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from jax.experimental.shard_map import shard_map
+from repro.launch.mesh import make_test_mesh
 from repro.launch.pipeline import pipeline_forward
 
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = make_test_mesh((8,), ("pod",))
 S, M, MB, D = 8, 4, 2, 16       # 8 stages, 4 microbatches
 rng = np.random.default_rng(0)
 Ws = jnp.asarray(rng.normal(size=(S, D, D)) / np.sqrt(D), jnp.float32)
@@ -102,8 +104,9 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from jax.experimental.shard_map import shard_map
 from repro.launch.collectives import compressed_psum
+from repro.launch.mesh import make_test_mesh
 
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = make_test_mesh((8,), ("pod",))
 rng = np.random.default_rng(1)
 x = jnp.asarray(rng.normal(size=(8, 64)), jnp.float32)
 

@@ -18,10 +18,11 @@ except ImportError:      # deterministic sweep, see _hypothesis_fallback.py
     from _hypothesis_fallback import given, settings, st
 
 from repro.kernels import (FUSED_MODES, CrossbarProgram, build_program,
-                           plan_fused_mlp, quantize_tensor, reram_linear,
-                           reram_mlp_fused, reram_mlp_fused_batched)
+                           chain_quantizer, plan_fused_mlp, quantize_tensor,
+                           requantize, reram_linear, reram_mlp_fused,
+                           reram_mlp_fused_batched)
 from repro.kernels.program import VMEM_BUDGET_BYTES, fused_vmem_bytes
-from repro.kernels.ref import combine_planes
+from repro.kernels.ref import combine_planes, quant_chain_np, quantize_np
 
 RNG = np.random.default_rng(0)
 
@@ -51,25 +52,6 @@ def _float_ref(layers, x, final_relu=True):
             lyr["b"], np.float64)
         if final_relu or i < len(layers) - 1:
             y = np.maximum(y, 0)
-    return y
-
-
-def _numpy_quant_chain(layers, x, final_relu=True):
-    """Correctly-rounded NumPy oracle of the quantized chain semantics:
-    float32 scale/quant/dequant ops (one rounding each, NumPy never
-    FMA-contracts), exact int64 matmuls."""
-    y = np.asarray(x, np.float32)
-    qmax = np.float32(127)
-    for i, lyr in enumerate(layers):
-        w = np.asarray(lyr["w"], np.float32)
-        b = np.asarray(lyr["b"], np.float32)
-        sx = np.maximum(np.max(np.abs(y)) / qmax, np.float32(1e-12))
-        xi = np.clip(np.round(y / sx), -qmax, qmax).astype(np.int64)
-        sw = np.maximum(np.max(np.abs(w)) / qmax, np.float32(1e-12))
-        wi = np.clip(np.round(w / sw), -qmax, qmax).astype(np.int64)
-        y = (xi @ wi).astype(np.float32) * (sx * sw) + b
-        if final_relu or i < len(layers) - 1:
-            y = np.maximum(y, np.float32(0))
     return y
 
 
@@ -142,8 +124,43 @@ def test_zero_bias_bitwise_vs_quantized_oracle(widths, m, final_relu):
     x = jnp.asarray(np.random.default_rng(2).normal(size=(m, widths[0])),
                     jnp.float32)
     fused = reram_mlp_fused(x, build_program(layers), final_relu=final_relu)
-    oracle = _numpy_quant_chain(layers, x, final_relu=final_relu)
+    oracle, _ = quant_chain_np(layers, x, final_relu=final_relu)
     assert np.array_equal(np.asarray(fused), oracle)
+
+
+@pytest.mark.parametrize("m,n", [(9, 7), (130, 64), (516, 200)])
+def test_requantize_matches_quantize_tensor_bitwise(m, n):
+    """The kernel's inter-layer requantizer, run alone, is
+    ``quantize_tensor`` and its NumPy twin bitwise where f32 division is
+    correctly rounded (here)."""
+    y = jnp.asarray(np.maximum(np.random.default_rng(3).normal(size=(m, n)),
+                               0), jnp.float32)
+    q, s = requantize(y)
+    q_ref, s_ref = quantize_tensor(y)
+    q_np, s_np = quantize_np(y)
+    assert q.dtype == jnp.int32 and np.array_equal(q, q_ref)
+    assert np.array_equal(np.asarray(q), q_np)
+    assert np.float32(s) == np.float32(s_ref) == s_np
+
+
+@pytest.mark.parametrize("widths,m,final_relu", [
+    ((4, 64, 64, 128), 516, True),
+    ((130, 200, 70), 257, False),
+])
+def test_chain_quantizer_oracle_bitwise(widths, m, final_relu):
+    """The oracle fed the program's own integers and the device's own
+    quantizer (``chain_quantizer``) gives the fused kernel's output and
+    the default oracle's accumulators, bitwise."""
+    layers = _mk_layers(widths, np.random.default_rng(4), zero_bias=True)
+    x = jnp.asarray(np.random.default_rng(5).normal(size=(m, widths[0])),
+                    jnp.float32)
+    prog = build_program(layers)
+    fused = reram_mlp_fused(x, prog, final_relu=final_relu)
+    y, accs = quant_chain_np(layers, x, final_relu=final_relu,
+                             quantize=chain_quantizer(prog))
+    _, accs_np = quant_chain_np(layers, x, final_relu=final_relu)
+    assert np.array_equal(np.asarray(fused), y)
+    assert all(np.array_equal(a, b) for a, b in zip(accs, accs_np))
 
 
 # ---------------------------------------------------------------------------
@@ -276,7 +293,7 @@ def test_modes_zero_bias_bitwise_vs_quantized_oracle(mode):
     x = jnp.asarray(np.random.default_rng(2).normal(size=(m, widths[0])),
                     jnp.float32)
     out = reram_mlp_fused(x, build_program(layers), mode=mode)
-    oracle = _numpy_quant_chain(layers, x)
+    oracle, _ = quant_chain_np(layers, x)
     assert np.array_equal(np.asarray(out), oracle)
 
 

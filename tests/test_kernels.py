@@ -1,4 +1,4 @@
-"""Pallas kernels vs pure-jnp oracles (interpret=True on CPU), with
+"""Pallas kernels vs pure-jnp oracles (the Pallas interpreter on CPU), with
 shape/dtype sweeps per the kernel contract."""
 import jax
 import jax.numpy as jnp

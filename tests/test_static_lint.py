@@ -212,13 +212,32 @@ class TestInterpretPinned:
         assert rules_hit(src) == ["interpret-pinned"]
 
     def test_threaded_flag_is_clean(self):
+        """The one clean form: the flag derived from the platform at the
+        call site (``repro.kernels.platform.interpret_mode``)."""
         src = """
         from jax.experimental import pallas as pl
+        from repro.kernels.platform import interpret_mode
 
-        def launch(x, *, interpret=True):
-            return pl.pallas_call(x, interpret=interpret)
+        def launch(x):
+            return pl.pallas_call(x, interpret=interpret_mode())
         """
         assert rules_hit(src) == []
+
+    @pytest.mark.parametrize("launch", [
+        "def launch(x, *, interpret=True):\n"
+        "            return pl.pallas_call(x, interpret=interpret)",
+        "def launch(x):\n"
+        "            return pl.pallas_call(x)",
+        "def launch(x):\n"
+        "            return pl.pallas_call(x, interpret=False)",
+    ], ids=["threaded-parameter", "omitted", "pinned-false"])
+    def test_flag_not_derived_triggers(self, launch):
+        src = f"""
+        from jax.experimental import pallas as pl
+
+        {launch}
+        """
+        assert rules_hit(src) == ["interpret-pinned"]
 
     def test_allowlist(self):
         src = """

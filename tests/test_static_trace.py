@@ -194,7 +194,7 @@ class TestSeededRegressions:
         def promoted_forward(x):
             return m.forward(x).astype(jnp.float64)
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             report = verify_contracts(_Proxy(m, forward=promoted_forward),
                                       jnp.asarray(np.asarray(cloud),
                                                   jnp.float32))
