@@ -67,7 +67,6 @@ def main(argv=None) -> None:
                              fig9a_traffic, fig9b_buffer_speedup)
     from .kernels_bench import kernels
     from .beyond_schedule import beyond
-    from .serve_bench import serve
     from .reliability_bench import reliability
 
     wls = workloads(seeds=(0,) if args.quick else (0, 1, 2))
@@ -79,7 +78,6 @@ def main(argv=None) -> None:
         ("fig10", lambda: fig10_hitrate(wls)),
         ("kernel", kernels),
         ("beyond", lambda: beyond(wls)),
-        ("serve", lambda: serve(16 if args.quick else 32)),
         ("reliability", lambda: reliability(4 if args.quick else 8)),
     ]
     meta = _metadata(args)

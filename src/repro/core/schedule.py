@@ -768,20 +768,24 @@ def device_build_plan(neighbors, last_points, *, intra: IntraMode = "index",
     batched plan). ``neighbors``/``last_points`` are the traced geometry
     outputs of the forward pass itself: neighbors[k-1] is layer k's
     (n_k, K) receptive fields, last_points the layer-L coordinates that
-    the intra order sorts."""
+    the intra order sorts. The intra order's ops sit under the named
+    scope ``order``, the coordination walk's under ``coordinate``."""
+    import jax
     import jax.numpy as jnp
 
     sizes = tuple(int(nb.shape[0]) for nb in neighbors)
-    if intra == "index":
-        last = jnp.arange(sizes[-1], dtype=jnp.int32)
-    elif intra == "greedy":
-        last = device_order_greedy(last_points, start=start)
-    elif intra == "morton":
-        last = device_order_morton(last_points, nbits=nbits)
-    else:
-        raise ValueError(f"unknown intra mode {intra!r}")
+    with jax.named_scope("order"):
+        if intra == "index":
+            last = jnp.arange(sizes[-1], dtype=jnp.int32)
+        elif intra == "greedy":
+            last = device_order_greedy(last_points, start=start)
+        elif intra == "morton":
+            last = device_order_morton(last_points, nbits=nbits)
+        else:
+            raise ValueError(f"unknown intra mode {intra!r}")
     if coordinated:
-        orders = device_coordinate(neighbors, last)
+        with jax.named_scope("coordinate"):
+            orders = device_coordinate(neighbors, last)
     else:
         orders = [jnp.arange(n, dtype=jnp.int32) for n in sizes[:-1]] + [last]
     return DevicePlan(orders, [_device_inverse(o) for o in orders], sizes,

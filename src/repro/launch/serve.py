@@ -35,6 +35,30 @@ repo's compiled artifacts into a request path:
 ``generate`` keeps its exact signature and stats keys but now runs as a
 thin client of the same engine. ``make_serve_step`` is unchanged — it is
 what the dry-run lowers for the decode_32k / long_500k shapes.
+
+Spans: the request path opens ``jax.profiler.TraceAnnotation`` host spans
+on the calling thread, which record nothing unless a profiler session is
+open. Each nests in the one that called it:
+
+  ``serve.step``            ``ServingEngine.step``; arg ``requests``, the
+                            served requests' ids, space-separated
+    ``serve.select``        the scheduler's ``select``
+    ``serve.run_batch``     ``PointCloudServable.run_batch``; args
+                            ``batch_real``, ``batch_bucket``,
+                            ``points_bucket``
+      ``serve.pad``         padding the batch on the host
+      ``serve.plan``        one cloud's plan lookup; arg ``hit`` (0/1)
+        ``serve.plan_key``  ``cloud_content_key``
+        ``serve.plan_build``  the plan builder on a miss
+      ``serve.plan_stack``  ``DevicePlan.stack``
+      ``serve.dispatch``    the batch's copy to the device and the serving
+                            step's call
+      ``serve.unpad``       slicing the real rows out of the logits
+
+The two device programs carry named scopes: the plan builder's ops sit
+under ``plan`` (``CompiledModel.build_device_plan``), the serving step's
+under ``step``, and below them ``geometry/fps``, ``geometry/knn``,
+``order``, ``coordinate``, ``gather``, ``mlp`` and ``head``.
 """
 from __future__ import annotations
 
@@ -53,6 +77,10 @@ from repro.configs.base import ArchConfig
 from repro.core.schedule import (DevicePlan, FrameTracker, PlanCache,
                                  cloud_content_key)
 from repro.models import lm
+
+#: a host span in the profiler's trace (nothing is recorded unless a
+#: profiler session is open); the module docstring lists them
+_span = jax.profiler.TraceAnnotation
 
 __all__ = [
     "ShapeBuckets",
@@ -85,8 +113,7 @@ class VirtualClock:
     by exactly ``tick_s`` on every ``monotonic()`` call instead, so each
     served batch costs one deterministic virtual tick and every latency
     percentile — and every deadline-miss decision — is a pure function
-    of the arrival stream and the scheduler. The seeded
-    ``serve/lidar_stream`` bench rows and the scheduler regression
+    of the arrival stream and the scheduler. The scheduler regression
     tests run on it."""
 
     def __init__(self, tick_s: float = 0.0, *, start: float = 0.0):
@@ -447,7 +474,6 @@ class PointCloudServable(Servable):
         self.requests = 0
         self.batches = 0
         self.jit_traces = 0
-        self.trace_shapes: list[tuple[int, int]] = []
         # the host-planning fallback (planned model, cache off, no traced
         # plan construction) cannot live under jit — everything else runs
         # through the ONE cached jitted step per bucket shape
@@ -481,74 +507,91 @@ class PointCloudServable(Servable):
     # bound: how often XLA recompiles the serving step
     def _step(self, clouds, n_valid, dplan):
         self.jit_traces += 1
-        self.trace_shapes.append((int(clouds.shape[0]),
-                                  int(clouds.shape[1])))
-        return self.model.batched_forward(clouds, n_valid=n_valid,
-                                          dplan=dplan)
+        with jax.named_scope("step"):
+            return self.model.batched_forward(clouds, n_valid=n_valid,
+                                              dplan=dplan)
 
     def bucket_of(self, payload) -> int:
         return self.buckets.point_bucket(np.asarray(payload).shape[0])
 
     def _plan_for(self, padded, n: int):
-        if self.frame_tracker is not None:
-            plan = self.frame_tracker.lookup(padded, n_valid=n)
-            if plan is not None:
-                return plan
-        key = cloud_content_key(padded, n_valid=n)
-        if self._jit_build is not None:
-            build = lambda: self._jit_build(jnp.asarray(padded),
-                                            jnp.int32(n))
-        else:
-            build = lambda: self.model.build_device_plan(padded, n_valid=n)
-        plan = self.plan_cache.get_or_build(key, build)
-        if self.frame_tracker is not None:
-            self.frame_tracker.update(padded, plan, n_valid=n)
-        return plan
+        with _span("serve.plan") as span:
+            if self.frame_tracker is not None:
+                plan = self.frame_tracker.lookup(padded, n_valid=n)
+                if plan is not None:
+                    span.set_metadata(hit=1)
+                    return plan
+            with _span("serve.plan_key"):
+                key = cloud_content_key(padded, n_valid=n)
+            misses = self.plan_cache.misses
+
+            def build():
+                with _span("serve.plan_build"):
+                    if self._jit_build is not None:
+                        return self._jit_build(jnp.asarray(padded),
+                                               jnp.int32(n))
+                    return self.model.build_device_plan(padded, n_valid=n)
+
+            plan = self.plan_cache.get_or_build(key, build)
+            span.set_metadata(hit=int(self.plan_cache.misses == misses))
+            if self.frame_tracker is not None:
+                self.frame_tracker.update(padded, plan, n_valid=n)
+            return plan
 
     def run_batch(self, payloads: list) -> list:
-        clouds = [np.asarray(p, np.float32) for p in payloads]
-        n_bucket = self.buckets.point_bucket(clouds[0].shape[0])
-        b_real = len(clouds)
-        b_bucket = self.buckets.batch_bucket(b_real)
-        if b_bucket == 1:
-            # never run a TRUE singleton batch: XLA collapses the unit
-            # batch dim and re-fuses the float matmuls, which breaks the
-            # bitwise tie between the batched step and the per-request
-            # eager forward; one replicated row keeps the vmapped program
-            # intact at negligible cost in the latency-bound regime
-            b_bucket = 2
-        if self.mesh is not None:                # whole slices per replica
-            n_rep = self.mesh.devices.size
-            b_bucket = -(-b_bucket // n_rep) * n_rep
-        padded = np.zeros((b_bucket, n_bucket, 3), np.float32)
-        n_valid = np.empty((b_bucket,), np.int32)
-        for i, c in enumerate(clouds):
-            padded[i, :c.shape[0]] = c
-            n_valid[i] = c.shape[0]
-        padded[b_real:] = padded[0]          # batch pads: replicate row 0
-        n_valid[b_real:] = n_valid[0]
+        with _span("serve.run_batch") as span:
+            with _span("serve.pad"):
+                clouds = [np.asarray(p, np.float32) for p in payloads]
+                n_bucket = self.buckets.point_bucket(clouds[0].shape[0])
+                b_real = len(clouds)
+                b_bucket = self.buckets.batch_bucket(b_real)
+                if b_bucket == 1:
+                    # never run a TRUE singleton batch: XLA collapses the
+                    # unit batch dim and re-fuses the float matmuls, which
+                    # breaks the bitwise tie between the batched step and
+                    # the per-request eager forward; one replicated row
+                    # keeps the vmapped program intact at negligible cost
+                    # in the latency-bound regime
+                    b_bucket = 2
+                if self.mesh is not None:        # whole slices per replica
+                    n_rep = self.mesh.devices.size
+                    b_bucket = -(-b_bucket // n_rep) * n_rep
+                padded = np.zeros((b_bucket, n_bucket, 3), np.float32)
+                n_valid = np.empty((b_bucket,), np.int32)
+                for i, c in enumerate(clouds):
+                    padded[i, :c.shape[0]] = c
+                    n_valid[i] = c.shape[0]
+                padded[b_real:] = padded[0]      # batch pads: replicate row 0
+                n_valid[b_real:] = n_valid[0]
+            span.set_metadata(batch_real=b_real, batch_bucket=b_bucket,
+                              points_bucket=n_bucket)
 
-        dplan = None
-        if self.plan_cache is not None:
-            plans = [self._plan_for(padded[i], int(n_valid[i]))
-                     for i in range(b_real)]
-            plans += [plans[0]] * (b_bucket - b_real)   # pads reuse row 0's
-            dplan = DevicePlan.stack(plans)
+            dplan = None
+            if self.plan_cache is not None:
+                plans = [self._plan_for(padded[i], int(n_valid[i]))
+                         for i in range(b_real)]
+                # the pads reuse row 0's plan
+                plans += [plans[0]] * (b_bucket - b_real)
+                with _span("serve.plan_stack"):
+                    dplan = DevicePlan.stack(plans)
 
-        clouds_d = jnp.asarray(padded)
-        nv_d = jnp.asarray(n_valid)
-        if self._jittable:
-            logits = self._jit_step(clouds_d, nv_d, dplan)
-        else:
-            logits = self.model.batched_forward(clouds_d, n_valid=nv_d)
-        self.requests += b_real
-        self.batches += 1
-        return list(logits[:b_real])
+            with _span("serve.dispatch"):
+                # the batch goes to the device after the plan builds are
+                # dispatched, so its copy overlaps their device time
+                clouds_d = jnp.asarray(padded)
+                nv_d = jnp.asarray(n_valid)
+                if self._jittable:
+                    logits = self._jit_step(clouds_d, nv_d, dplan)
+                else:
+                    logits = self.model.batched_forward(clouds_d, n_valid=nv_d)
+            self.requests += b_real
+            self.batches += 1
+            with _span("serve.unpad"):
+                return list(logits[:b_real])
 
     def stats(self) -> dict:
         s = {"requests": self.requests, "batches": self.batches,
-             "jit_traces": self.jit_traces,
-             "trace_shapes": list(self.trace_shapes)}
+             "jit_traces": self.jit_traces}
         if self.plan_cache is not None:
             s["plan_cache"] = self.plan_cache.stats()
         if self.frame_tracker is not None:
@@ -681,8 +724,7 @@ class ServingEngine:
     stream against a virtual clock — service time is measured on the
     injectable ``clock`` (wall by default; a :class:`VirtualClock` makes
     every percentile and deadline decision deterministic) — and reports
-    p50/p99 request latency, throughput and deadline-miss rate, the
-    serve bench's measurement core.
+    p50/p99 request latency, throughput and deadline-miss rate.
 
     The engine also keeps a per-(bucket, batch-size) EMA of measured
     batch service time (:meth:`service_estimate`), which deadline-aware
@@ -763,17 +805,21 @@ class ServingEngine:
     def step(self, *, now: float = 0.0) -> list[Request]:
         """Serve ONE scheduler-selected batch and return the completed
         requests; [] when the queue is empty."""
-        batch = self.scheduler.select(
-            bucket_of=self.servable.bucket_of, max_batch=self.max_batch,
-            now=now, est_service=self.service_estimate)
-        if not batch:
-            return []
-        results = self.servable.run_batch([r.payload for r in batch])
-        for req, res in zip(batch, results):
-            req.result = res
-            req.t_done = now
-        self.completed.extend(batch)
-        return batch
+        with _span("serve.step") as span:
+            with _span("serve.select"):
+                batch = self.scheduler.select(
+                    bucket_of=self.servable.bucket_of,
+                    max_batch=self.max_batch, now=now,
+                    est_service=self.service_estimate)
+            if not batch:
+                return []
+            span.set_metadata(requests=" ".join(str(r.id) for r in batch))
+            results = self.servable.run_batch([r.payload for r in batch])
+            for req, res in zip(batch, results):
+                req.result = res
+                req.t_done = now
+            self.completed.extend(batch)
+            return batch
 
     def drain(self, *, now: float = 0.0) -> list[Request]:
         """Step until the queue is empty; returns everything completed by

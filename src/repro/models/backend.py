@@ -536,16 +536,18 @@ class CompiledModel:
         (jit-safe) under on-device planning, on host otherwise; returns
         the compile-time plan unchanged when one is bound. ``n_valid``
         masks shape-bucket pad rows out of the geometry, so the plan
-        equals the unpadded cloud's."""
+        equals the unpadded cloud's. Traced, its ops sit under the named
+        scope ``plan``."""
         if not self._planned:
             raise ValueError("this model's schedule is unplanned "
                              "('baseline'); there is no plan to build")
         if self._dplan is not None:
             return self._dplan
-        pts_list, ctr_list, nbr_list = self._geometry_pass(
-            jnp.asarray(cloud), n_valid)
-        if self._device_planning:
-            return self._traced_plan(pts_list, nbr_list)
+        with jax.named_scope("plan"):
+            pts_list, ctr_list, nbr_list = self._geometry_pass(
+                jnp.asarray(cloud), n_valid)
+            if self._device_planning:
+                return self._traced_plan(pts_list, nbr_list)
         return self._device_plan_for(pts_list, ctr_list, nbr_list)
 
     # -- execution ----------------------------------------------------------
@@ -887,7 +889,9 @@ class CompiledModel:
         on geometry — the whole thing is ONE jittable clouds→logits
         computation (``jit_batched_forward`` wraps it). Same arithmetic
         per row as the host-planned path, so logits stay bitwise equal to
-        it."""
+        it. Each SA layer's ops sit under the named scopes ``gather`` and
+        ``mlp`` (the MLP, the max over K and the scatter back to index
+        order), the classifier's under ``head``."""
         cfg = self.config
         batch = clouds.shape[0]
         feats = jax.vmap(
@@ -919,15 +923,18 @@ class CompiledModel:
             if not dplan.batched:                 # one plan shared batch-wide
                 order = jnp.broadcast_to(order, (batch,) + order.shape)
                 inv = jnp.broadcast_to(inv, (batch,) + inv.shape)
-            nbr_o = jnp.take_along_axis(nbr_s[k].astype(jnp.int32),
-                                        order[:, :, None], axis=1)
-            ctr_o = jnp.take_along_axis(ctr_s[k].astype(jnp.int32),
-                                        order, axis=1)
-            diff = aggregate_diff_batched(feats, nbr_o, ctr_o)  # ONE launch
-            h = self._apply_sa_mlp_batched(k, diff)
-            out = jnp.max(h, axis=2)                     # reduction over K
-            feats = jnp.take_along_axis(out, inv[:, :, None], axis=1)
-        return self._head_batched(feats)
+            with jax.named_scope("gather"):
+                nbr_o = jnp.take_along_axis(nbr_s[k].astype(jnp.int32),
+                                            order[:, :, None], axis=1)
+                ctr_o = jnp.take_along_axis(ctr_s[k].astype(jnp.int32),
+                                            order, axis=1)
+                diff = aggregate_diff_batched(feats, nbr_o, ctr_o)
+            with jax.named_scope("mlp"):
+                h = self._apply_sa_mlp_batched(k, diff)
+                out = jnp.max(h, axis=2)                 # reduction over K
+                feats = jnp.take_along_axis(out, inv[:, :, None], axis=1)
+        with jax.named_scope("head"):
+            return self._head_batched(feats)
 
     def _apply_sa_mlp_batched(self, k, diff):
         if self.backend.batched_in_grid:

@@ -183,18 +183,26 @@ def geometry_pass(config: PointNetConfig, cloud: jnp.ndarray, *,
     (serving tier): it masks the FIRST layer's FPS/kNN only — every later
     layer operates on FPS-selected real points, so the rest of the pass is
     untouched and the whole geometry is bitwise-equal to the unpadded
-    cloud's (the bucketing contract in ``repro.models.backend``)."""
+    cloud's (the bucketing contract in ``repro.models.backend``).
+
+    Its ops sit under the named scopes ``geometry/fps`` and
+    ``geometry/knn``, so a profile tells the two apart wherever the pass
+    is traced."""
     pts_list, ctr_list, nbr_list = [cloud], [None], [None]
     pts = cloud
-    for li, spec in enumerate(config.layers):
-        nv = n_valid if li == 0 else None
-        centers = farthest_point_sample(pts, spec.n_centers, n_valid=nv)
-        c_pts = pts[centers]
-        nbr = knn(c_pts, pts, spec.n_neighbors, n_valid=nv)
-        pts_list.append(c_pts)
-        ctr_list.append(centers)
-        nbr_list.append(nbr)
-        pts = c_pts
+    with jax.named_scope("geometry"):
+        for li, spec in enumerate(config.layers):
+            nv = n_valid if li == 0 else None
+            with jax.named_scope("fps"):
+                centers = farthest_point_sample(pts, spec.n_centers,
+                                                n_valid=nv)
+                c_pts = pts[centers]
+            with jax.named_scope("knn"):
+                nbr = knn(c_pts, pts, spec.n_neighbors, n_valid=nv)
+            pts_list.append(c_pts)
+            ctr_list.append(centers)
+            nbr_list.append(nbr)
+            pts = c_pts
     return pts_list, ctr_list, nbr_list
 
 

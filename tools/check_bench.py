@@ -2,16 +2,15 @@
 committed baseline and fail CI on wall-clock regressions.
 
 Only rows whose names match a STABLE prefix are gated — interpret-mode
-host timings jitter, but the gated rows (compiled plan construction,
-steady-state serving throughput) are warmed before measurement and have
-stayed reproducible run-to-run. Rows present in only one file are
-reported but never fail the gate, EXCEPT prefixes named via ``--require``:
-those must appear in the new run (this is how CI notices a bench silently
-dropping out of the harness).
+host timings jitter, but the gated rows (compiled plan construction) are
+warmed before measurement and have stayed reproducible run-to-run. Rows
+present in only one file are reported but never fail the gate, EXCEPT
+prefixes named via ``--require``: those must appear in the new run (this
+is how CI notices a bench silently dropping out of the harness).
 
 Run:  PYTHONPATH=src python tools/check_bench.py NEW.json \\
           [--baseline BENCH_20260808T115407Z.json] [--threshold 0.20] \\
-          [--require serve/stream] [--gate plan/device_build --gate serve/]
+          [--require reliability/pareto] [--gate plan/device_build]
 CI runs it after the bench smoke steps on every push.
 """
 from __future__ import annotations
@@ -28,9 +27,8 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 #: baseline)
 DEFAULT_BASELINE = "BENCH_20260808T125424Z.json"
 
-#: rows stable enough to gate: compiled (jitted) plan construction and the
-#: warmed serving stream
-DEFAULT_GATES = ("plan/device_build", "serve/")
+#: rows stable enough to gate: compiled (jitted) plan construction
+DEFAULT_GATES = ("plan/device_build",)
 
 
 def load_rows(path: pathlib.Path) -> dict[str, float]:
