@@ -688,8 +688,8 @@ def device_order_morton(points, nbits: int = 10):
 
 def device_coordinate(neighbors, last_order):
     """Device twin of :func:`coordinate_layers` (paper Algorithm 1 lines
-    9-13): the recursive receptive-field walk re-expressed as an iterative
-    ``lax.scan`` over the last-layer order with per-layer visited masks.
+    9-13): the recursive receptive-field walk in closed form, one
+    loop-free pass per layer from the top down.
 
     neighbors[k-1] : (n_k, K_k) device int array — layer k's receptive
                      fields, indices into layer k-1 (k = 1..L; the layer-1
@@ -701,54 +701,38 @@ def device_coordinate(neighbors, last_order):
     :class:`DevicePlan` layout: the walk's partial order with the orphan
     points (outside every last-layer receptive field) appended at the tail
     in ascending index order — exactly ``complete_order`` of the host
-    walk's output, bit-identical (tested). Each scan step schedules one
-    last-layer point: its not-yet-visited pyramid members depth-first in
-    row order (the host recursion's visit order), then the point itself;
-    visited masks implement the "calculated once" dedup."""
+    walk's output, bit-identical (tested).
+
+    The host recursion visits layer k in this order: the live (walked)
+    layer-(k+1) points in their execution order, each one's receptive
+    field row by row, each index kept at its first occurrence only
+    ("calculated once"). So each layer is one scatter-min of flat row
+    positions (the first occurrence of every index; positions under an
+    orphan parent, which the walk never enters, are masked to a sentinel)
+    and one argsort whose key puts the visited indices in first-occurrence
+    order and the orphans after them in ascending index order."""
     import jax.numpy as jnp
-    from jax import lax
 
     nbrs = [jnp.asarray(nb, jnp.int32) for nb in neighbors]
     L = len(nbrs)
-    sizes = [int(nb.shape[0]) for nb in nbrs]
-
-    def exec_point(k, i, st):
-        """execute(k, i) of the host recursion: skip if visited, else
-        visit members (k > 1) then append i to layer k's order."""
-        def visit(st):
-            if k > 1:
-                st, _ = lax.scan(
-                    lambda c, m: (exec_point(k - 1, m, c), None),
-                    st, nbrs[k - 1][i])
-            orders, ptrs, dones = (list(st[0]), list(st[1]), list(st[2]))
-            orders[k - 1] = orders[k - 1].at[ptrs[k - 1]].set(i)
-            dones[k - 1] = dones[k - 1].at[i].set(True)
-            ptrs[k - 1] = ptrs[k - 1] + 1
-            return tuple(orders), tuple(ptrs), tuple(dones)
-
-        return lax.cond(st[2][k - 1][i], lambda s: s, visit, st)
-
-    st0 = (tuple(jnp.zeros(n, jnp.int32) for n in sizes),
-           tuple(jnp.zeros((), jnp.int32) for _ in sizes),
-           tuple(jnp.zeros(n, jnp.bool_) for n in sizes))
-    st, _ = lax.scan(lambda c, j: (exec_point(L, j, c), None),
-                     st0, jnp.asarray(last_order, jnp.int32))
-    orders, ptrs, dones = st
-    return [_device_complete(o, p, d)
-            for o, p, d in zip(orders, ptrs, dones)]
-
-
-def _device_complete(order, ptr, done):
-    """Orphan-complete a partial device order in place: scatter the
-    unvisited indices (ascending — matching ``complete_order``'s sorted
-    ``setdiff1d`` tail) into the slots after ``ptr``."""
-    import jax.numpy as jnp
-
-    n = order.shape[0]
-    orphan = ~done
-    offs = jnp.cumsum(orphan.astype(jnp.int32)) - orphan.astype(jnp.int32)
-    pos = jnp.where(orphan, ptr + offs, n)        # n = out-of-bounds drop
-    return order.at[pos].set(jnp.arange(n, dtype=jnp.int32), mode="drop")
+    order = jnp.asarray(last_order, jnp.int32)
+    live = jnp.ones(order.shape[0], jnp.bool_)
+    orders = [order]
+    for k in range(L - 1, 0, -1):
+        n_k = nbrs[k - 1].shape[0]
+        rows = nbrs[k][order]                       # (n_{k+1}, K), walk order
+        sentinel = rows.size
+        pos = jnp.arange(sentinel, dtype=jnp.int32).reshape(rows.shape)
+        pos = jnp.where(live[:, None], pos, sentinel)
+        first = (jnp.full(n_k, sentinel, jnp.int32)
+                 .at[rows.ravel()].min(pos.ravel()))
+        visited = first < sentinel
+        key = jnp.where(visited, first,
+                        sentinel + jnp.arange(n_k, dtype=jnp.int32))
+        order = jnp.argsort(key).astype(jnp.int32)
+        live = visited[order]
+        orders.append(order)
+    return orders[::-1]
 
 
 def _device_inverse(order):

@@ -15,8 +15,8 @@ try:
 except ImportError:      # deterministic sweep, see _hypothesis_fallback.py
     from _hypothesis_fallback import given, settings, st
 
-from repro.core import (DevicePlan, PointNetConfig, PointNetWorkload,
-                        SALayerSpec, build_plan)
+from repro.core import (PAPER_MODELS, DevicePlan, PointNetConfig,
+                        PointNetWorkload, SALayerSpec, build_plan)
 from repro.core.schedule import (GREEDY_DENSE_LIMIT, complete_order,
                                  coordinate_layers, device_build_plan,
                                  device_coordinate, device_order_greedy,
@@ -163,6 +163,94 @@ def test_device_coordinate_orphan_completion_ragged(seed, c2):
         [wl.neighbors[k] for k in range(1, wl.n_layers + 1)], last)
     for k, (h, d) in enumerate(zip(host, dev), start=1):
         assert np.array_equal(np.asarray(d), h), k
+
+
+def three_layer_config(n=128, c1=64, c2=16, c3=4, k=4):
+    return PointNetConfig(name="tiny3", n_points=n, layers=(
+        SALayerSpec(n_centers=c1, n_neighbors=k, in_features=4,
+                    mlp=(4, 8, 8, 16)),
+        SALayerSpec(n_centers=c2, n_neighbors=k, in_features=16,
+                    mlp=(16, 16, 16, 32)),
+        SALayerSpec(n_centers=c3, n_neighbors=k, in_features=32,
+                    mlp=(32, 32, 32, 64)),
+    ))
+
+
+def _device_coordinated(wl, last):
+    return device_coordinate(
+        [wl.neighbors[k] for k in range(1, wl.n_layers + 1)], last)
+
+
+@pytest.mark.parametrize("c3,sparse", [(12, False), (2, True)])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_device_coordinate_three_layers_matches_host_walk(seed, c3, sparse):
+    """Three layers: the walk recurses through layer 2, where a sparse top
+    layer leaves orphan parents whose fields must not schedule layer 1."""
+    wl = PointNetWorkload.random(three_layer_config(c3=c3), seed=seed)
+    last = morton_order(wl.points[-1])
+    walked_l2 = coordinate_layers(wl, last).order_of(2).shape[0]
+    assert (walked_l2 < wl.points[2].shape[0]) == sparse
+    host = _host_coordinated_completed(wl, last)
+    for k, (h, d) in enumerate(zip(host, _device_coordinated(wl, last)),
+                               start=1):
+        assert np.array_equal(np.asarray(d), h), k
+
+
+@pytest.fixture(scope="module")
+def published_workload():
+    """The served geometry: 1,024 points, 512/128 centres, K = 16."""
+    return PointNetWorkload.random(PAPER_MODELS["model0"], seed=3)
+
+
+@pytest.mark.parametrize("intra", ["index", "morton", "greedy"])
+def test_device_coordinate_published_geometry(published_workload, intra):
+    wl = published_workload
+    assert [p.shape[0] for p in wl.points] == [1024, 512, 128]
+    last = {"index": np.arange(wl.points[-1].shape[0]),
+            "morton": morton_order(wl.points[-1]),
+            "greedy": greedy_nn_order(wl.points[-1])}[intra]
+    host = _host_coordinated_completed(wl, last)
+    for k, (h, d) in enumerate(zip(host, _device_coordinated(wl, last)),
+                               start=1):
+        assert np.array_equal(np.asarray(d), h), (intra, k)
+
+
+def test_device_coordinate_vmaps_per_cloud():
+    """A vmapped walk over a batch of clouds equals the per-cloud walks."""
+    import jax
+    wls = [PointNetWorkload.random(three_layer_config(c3=c3), seed=s)
+           for s, c3 in ((4, 2), (5, 2), (6, 2))]
+    nbrs = [np.stack([w.neighbors[k] for w in wls]).astype(np.int32)
+            for k in range(1, 4)]
+    lasts = np.stack([morton_order(w.points[-1]) for w in wls]
+                     ).astype(np.int32)
+    batched = jax.vmap(device_coordinate)(nbrs, lasts)
+    for b, wl in enumerate(wls):
+        single = _device_coordinated(wl, lasts[b])
+        for k, (bk, sk) in enumerate(zip(batched, single), start=1):
+            assert np.array_equal(np.asarray(bk)[b], np.asarray(sk)), (b, k)
+
+
+def test_device_coordinate_has_no_loop_or_branch():
+    """The walk is a fixed number of vectorised ops: no scan, while or cond
+    anywhere in its jaxpr."""
+    import jax
+    wl = PointNetWorkload.random(three_layer_config(), seed=0)
+    nbrs = [wl.neighbors[k].astype(np.int32) for k in range(1, 4)]
+    last = np.arange(wl.points[-1].shape[0], dtype=np.int32)
+    jaxpr = jax.make_jaxpr(device_coordinate)(nbrs, last)
+
+    def primitives(jx):
+        for eqn in jx.eqns:
+            yield eqn.primitive.name
+            for v in eqn.params.values():   # nested jaxprs: pjit, branches
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from primitives(sub)
+
+    found = set(primitives(jaxpr.jaxpr))
+    assert not found & {"scan", "while", "cond"}, found
 
 
 # ---------------------------------------------------------------------------

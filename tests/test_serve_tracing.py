@@ -83,9 +83,9 @@ def test_op_names_carry_every_stage_scope(model0_programs, which, scopes):
 
 
 def test_builder_loops_lie_under_a_stage_scope(model0_programs):
-    """Every ``while`` of the builder's entry computation (FPS, the greedy
-    order, the coordination walk) is under one of the stage scopes, so
-    the stages' device time covers the loops whole."""
+    """Every ``while`` of the builder's entry computation (FPS and the
+    greedy order; the coordination pass has none) is under one of the
+    stage scopes, so the stages' device time covers the loops whole."""
     builder, _ = model0_programs
     entry = builder[builder.index("\nENTRY"):]
     entry = entry[:entry.index("\n}")]
