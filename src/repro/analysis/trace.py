@@ -320,14 +320,15 @@ def _vmem_contract(model, budget: int) -> tuple[dict, list[ContractViolation]]:
     cache = getattr(model.backend, "_plan_cache", None)
     if not cache:
         return rows, violations
+    from repro.core.workload import mlp_label
     n_layers = model.config.n_layers
     for (key, m_rows), fp in sorted(cache.items(), key=lambda kv: str(kv[0])):
-        label = "head" if key == "head" else f"sa{key[1]}"
+        label = mlp_label(key)
         rows[f"{label}@{m_rows}"] = {
             "mode": fp.mode, "vmem_bytes": fp.vmem_bytes,
             "fits_budget": fp.fits_budget}
         if fp.vmem_bytes > budget:
-            layer = n_layers if key == "head" else key[1]
+            layer = key[1] if isinstance(key, tuple) else n_layers
             violations.append(ContractViolation(
                 "vmem-budget",
                 f"fused plan for MLP '{label}' at {m_rows} rows "
@@ -335,6 +336,15 @@ def _vmem_contract(model, budget: int) -> tuple[dict, list[ContractViolation]]:
                 f"the {budget} B budget",
                 primitive=f"reram_mlp_fused_{fp.mode}", layer=layer))
     return rows, violations
+
+
+def _gather_launches(config, batch: int | None) -> int:
+    """The gather launches a planned forward makes: one per SA layer and
+    branch, times the chunks its index stream takes."""
+    from repro.kernels.aggregate import index_chunks
+    from repro.models.pointnet2 import branch_bounds
+    return sum(len(index_chunks(batch or 1, spec.n_centers, hi - lo))
+               for spec in config.layers for lo, hi in branch_bounds(spec))
 
 
 def verify_contracts(model, x, *, rules: tuple = CONTRACTS,
@@ -349,11 +359,14 @@ def verify_contracts(model, x, *, rules: tuple = CONTRACTS,
       * ``traceable``      — the pipeline traces end to end under
         ``jax.make_jaxpr`` (host-planning fallbacks violate this by
         design: their plan is built from concrete geometry);
-      * ``gather-launches``— exactly ``n_layers`` gather launches for a
+      * ``gather-launches``— exactly one gather launch per SA layer (per
+        branch of a multi-scale layer; more where an index stream is
+        fetched in chunks, ``kernels.aggregate.index_chunks``) for a
         planned model (0 for baseline), batch-gridded with the full
         batch and never the per-cloud kernel in a batched trace;
       * ``mlp-launches``   — batch-in-grid backends fuse each MLP into
-        ONE launch: ``n_layers + 1`` fused-MLP launches (head included);
+        ONE launch: one per SA MLP (per branch), the group-all MLP and
+        the head (``PointNetConfig.mlp_keys``);
       * ``host-callbacks`` — zero host-callback primitives in the jaxpr
         (and, with ``check_hlo=True``, zero callback custom-calls in the
         optimized HLO);
@@ -377,7 +390,8 @@ def verify_contracts(model, x, *, rules: tuple = CONTRACTS,
                          f"got shape {tuple(x.shape)}")
     n_layers = model.config.n_layers
     if expected_gather_launches is None:
-        expected_gather_launches = n_layers if model.planned else 0
+        expected_gather_launches = (_gather_launches(model.config, batch)
+                                    if model.planned else 0)
     report = ContractReport(
         backend=model.backend_name, schedule=model.schedule,
         expected_gather_launches=expected_gather_launches,
@@ -398,13 +412,13 @@ def verify_contracts(model, x, *, rules: tuple = CONTRACTS,
         report.violations += _batched_purity(info, batch,
                                              expected_gather_launches)
     if "mlp-launches" in rules and model.backend.batched_in_grid:
-        expected_mlp = n_layers + 1            # one per SA MLP + the head
+        expected_mlp = len(model.config.mlp_keys())
         if info.mlp_launches != expected_mlp:
             report.violations.append(ContractViolation(
                 "mlp-launches",
                 f"batch-in-grid backend must fuse each MLP into ONE "
                 f"launch: expected {expected_mlp} fused-MLP launches "
-                f"({n_layers} SA + head), got {info.mlp_launches}",
+                f"({expected_mlp - 1} SA + head), got {info.mlp_launches}",
                 primitive="reram_mlp_fused",
                 layer=min(info.mlp_launches, n_layers)))
     if "host-callbacks" in rules:

@@ -12,12 +12,14 @@ Terminology follows the paper:
   - ``centers[k][i]`` is the index *into layer k-1's point set* of the i-th
     output point of layer k (FPS selects a subset),
   - ``neighbors[k][i]`` are the K nearest layer-(k-1) points of that center
-    (the receptive field of one SA step),
+    (the receptive field of one SA step) — for a multi-scale (MSG) layer,
+    each branch's ball query concatenated, ``sum(K_b)`` indices,
   - features of layer k-1 are fetched per neighbor during aggregation; this
     fetch is the DRAM-traffic bottleneck the paper attacks.
 """
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass, field
 from typing import Sequence
 
@@ -25,11 +27,19 @@ import numpy as np
 
 __all__ = [
     "SALayerSpec",
+    "BallBranch",
+    "MSGLayerSpec",
+    "GroupAllSpec",
     "PointNetConfig",
     "PointNetWorkload",
     "farthest_point_sample_np",
+    "map_mlps",
+    "mlp_label",
+    "select_mlp",
     "knn_np",
+    "ball_query_np",
     "PAPER_MODELS",
+    "POINTNET2_MSG",
 ]
 
 
@@ -61,14 +71,149 @@ class SALayerSpec:
 
 
 @dataclass(frozen=True)
+class BallBranch:
+    """One scale of a multi-scale grouping layer: a ball query of
+    ``radius`` keeping ``n_neighbors`` indices, then its own MLP over the
+    grouped rows ``[p_j - p_i ; f_j]`` (so ``mlp[0]`` is 3 + the layer's
+    ``in_features``)."""
+
+    radius: float
+    n_neighbors: int
+    mlp: tuple[int, ...]
+
+
+@dataclass(frozen=True)
+class MSGLayerSpec:
+    """A PointNet++ multi-scale grouping (MSG) set-abstraction layer
+    (Qi et al. 2017, §3.3): FPS centres, then per branch a ball query,
+    the grouped rows ``[p_j - p_i ; f_j]``, the branch MLP and a max over
+    its ``n_neighbors`` rows; the branches' outputs are concatenated.
+
+    ``in_features`` is the width of the previous layer's features
+    ``f_j`` (3 normal channels at layer 0). ``n_neighbors`` is the width
+    of the concatenated receptive field, ``sum(K_b)``: the plan orders a
+    centre's branches as one row of indices."""
+
+    n_centers: int
+    in_features: int
+    branches: tuple[BallBranch, ...]
+
+    def __post_init__(self):
+        for b in self.branches:
+            if b.mlp[0] != 3 + self.in_features:
+                raise ValueError(
+                    f"branch MLP {b.mlp} must take 3 + in_features = "
+                    f"{3 + self.in_features} inputs ([p_j - p_i ; f_j])")
+
+    @property
+    def n_neighbors(self) -> int:
+        return sum(b.n_neighbors for b in self.branches)
+
+    @property
+    def out_features(self) -> int:
+        return sum(b.mlp[-1] for b in self.branches)
+
+
+@dataclass(frozen=True)
+class GroupAllSpec:
+    """PointNet++'s last set abstraction, ``group_all``: one MLP over
+    every remaining point's absolute row ``[p_j ; f_j]``, then a max over
+    the points. It has a single centre, so it lies outside the plan."""
+
+    in_features: int
+    mlp: tuple[int, ...]
+
+    def __post_init__(self):
+        if self.mlp[0] != 3 + self.in_features:
+            raise ValueError(f"group-all MLP {self.mlp} must take 3 + "
+                             f"in_features = {3 + self.in_features} inputs")
+
+    @property
+    def out_features(self) -> int:
+        return self.mlp[-1]
+
+
+@dataclass(frozen=True)
 class PointNetConfig:
+    """``layers`` are the planned set-abstraction layers (each with its
+    own centres); ``group_all`` the optional final group-all layer;
+    ``head`` the classifier's hidden widths, between the global feature
+    and the classes."""
+
     name: str
     n_points: int
-    layers: tuple[SALayerSpec, ...]
+    layers: tuple[SALayerSpec | MSGLayerSpec, ...]
+    group_all: GroupAllSpec | None = None
+    head: tuple[int, ...] = (256,)
 
     @property
     def n_layers(self) -> int:
         return len(self.layers)
+
+    @property
+    def out_features(self) -> int:
+        """Width of the global feature the head takes."""
+        last = self.group_all if self.group_all is not None \
+            else self.layers[-1]
+        return last.out_features
+
+    def layer_mlp_keys(self, i: int) -> list:
+        """The MLP keys of SA layer ``i``: ``("sa", i)``, or ``("sa", i, b)``
+        per branch b of a multi-scale layer."""
+        spec = self.layers[i]
+        if isinstance(spec, MSGLayerSpec):
+            return [("sa", i, b) for b in range(len(spec.branches))]
+        return [("sa", i)]
+
+    def mlp_keys(self) -> list:
+        """Every MLP key of the model in execution order: the SA layers'
+        (:meth:`layer_mlp_keys`), ``"sa_all"`` where there is a group-all
+        layer, ``"head"``."""
+        keys = [k for i in range(self.n_layers) for k in self.layer_mlp_keys(i)]
+        if self.group_all is not None:
+            keys.append("sa_all")
+        return keys + ["head"]
+
+
+def mlp_label(key) -> str:
+    """``sa0``, ``sa1.b2``, ``sa_all``, ``head``: an MLP key as a name."""
+    if isinstance(key, str):
+        return key
+    return f"sa{key[1]}" + (f".b{key[2]}" if len(key) > 2 else "")
+
+
+# A model's MLPs sit in a ``{"sa": [...], "sa_all", "head"}`` tree of
+# parameters (an MLP is a list of ``{"w", "b"}`` layers) or of programs
+# (an MLP is one ``CrossbarProgram``). An ``sa`` entry holds one MLP, or a
+# list of one MLP per branch for a multi-scale layer; the two functions
+# below are the only ones that tell those apart.
+
+def _branched(entry) -> bool:
+    return isinstance(entry, list) and not isinstance(entry[0], dict)
+
+
+def select_mlp(tree: dict, key):
+    """The MLP of ``tree`` (parameters or programs) under ``key``
+    (:meth:`PointNetConfig.mlp_keys`)."""
+    if isinstance(key, str):
+        return tree[key]
+    entry = tree["sa"][key[1]]
+    return entry[key[2]] if len(key) > 2 else entry
+
+
+def map_mlps(fn, tree: dict) -> dict:
+    """``tree`` in the same layout with ``fn(ix, mlp)`` applied to every
+    MLP. ``ix`` is the MLP's seed index: 0 for the head, then 1, 2, ... in
+    :meth:`PointNetConfig.mlp_keys` order, so SA layer i of a single-scale
+    model is i + 1."""
+    ix = itertools.count(1)
+    one = lambda mlp: fn(next(ix), mlp)
+    out = {"sa": [[one(m) for m in e] if _branched(e) else one(e)
+                  for e in tree["sa"]]}
+    if "sa_all" in tree:
+        out["sa_all"] = one(tree["sa_all"])
+    out["head"] = fn(0, tree["head"])
+    return out
 
 
 def _paper_model(name: str, f0: int, w1: int, w2: int) -> PointNetConfig:
@@ -102,6 +247,30 @@ PAPER_MODELS: dict[str, PointNetConfig] = {
 }
 
 
+#: PointNet++ MSG classification (Qi et al., NeurIPS 2017, arXiv:1706.02413;
+#: ``models/pointnet2_cls_msg.py`` of github.com/charlesq34/pointnet2): 1,024
+#: points with 3 normal channels, three scales per SA layer, group-all, and a
+#: (1024, 512, 256, 40) head.
+POINTNET2_MSG = PointNetConfig(
+    name="pointnet2-msg",
+    n_points=1024,
+    layers=(
+        MSGLayerSpec(n_centers=512, in_features=3, branches=(
+            BallBranch(0.1, 16, (6, 32, 32, 64)),
+            BallBranch(0.2, 32, (6, 64, 64, 128)),
+            BallBranch(0.4, 128, (6, 64, 96, 128)),
+        )),
+        MSGLayerSpec(n_centers=128, in_features=320, branches=(
+            BallBranch(0.2, 32, (323, 64, 64, 128)),
+            BallBranch(0.4, 64, (323, 128, 128, 256)),
+            BallBranch(0.8, 128, (323, 128, 128, 256)),
+        )),
+    ),
+    group_all=GroupAllSpec(in_features=640, mlp=(643, 256, 512, 1024)),
+    head=(512, 256),
+)
+
+
 def farthest_point_sample_np(points: np.ndarray, n_samples: int,
                              start: int = 0) -> np.ndarray:
     """Classic FPS. ``points``: (N, 3). Returns indices (n_samples,).
@@ -129,6 +298,39 @@ def knn_np(queries: np.ndarray, points: np.ndarray, k: int) -> np.ndarray:
     return np.argsort(d, axis=1, kind="stable")[:, :k]
 
 
+def ball_query_np(queries: np.ndarray, points: np.ndarray, radius: float,
+                  k: int) -> np.ndarray:
+    """Indices (Q, k): per query the first ``k`` points, in index order,
+    with squared distance (x, y and z accumulated in that order) below
+    ``radius**2``; slots past the last hit repeat the first hit, as the
+    reference code's ``query_ball_point`` fills them. Squared distances
+    and ``radius**2`` are formed in the points' dtype."""
+    dt = points.dtype
+    r2 = dt.type(radius) * dt.type(radius)
+    d = (queries[:, None, 0] - points[None, :, 0]) ** 2
+    for c in range(1, points.shape[1]):
+        d = d + (queries[:, None, c] - points[None, :, c]) ** 2
+    out = np.empty((queries.shape[0], k), np.int64)
+    for q, row in enumerate(d < r2):
+        hits = np.flatnonzero(row)[:k]
+        if hits.size == 0:
+            raise ValueError(f"query {q} has no point within radius "
+                             f"{radius}")
+        out[q] = hits[0]
+        out[q, :hits.size] = hits
+    return out
+
+
+def _receptive_fields(spec, prev: np.ndarray, c: np.ndarray) -> np.ndarray:
+    """A layer's receptive fields on the host: kNN for SSG, each branch's
+    ball query concatenated for MSG."""
+    if isinstance(spec, MSGLayerSpec):
+        return np.concatenate([ball_query_np(prev[c], prev, b.radius,
+                                             b.n_neighbors)
+                               for b in spec.branches], axis=1)
+    return knn_np(prev[c], prev, spec.n_neighbors)
+
+
 @dataclass
 class PointNetWorkload:
     """A concrete (point cloud x config) instance: everything the scheduler
@@ -146,16 +348,22 @@ class PointNetWorkload:
 
     @classmethod
     def build(cls, cloud: np.ndarray, config: PointNetConfig) -> "PointNetWorkload":
+        """Host geometry of ``cloud`` under ``config``, computed in the
+        cloud's own floating dtype (float64 for an integer cloud): a
+        float32 cloud makes the device's ball-query decisions."""
         if cloud.shape[0] != config.n_points:
             raise ValueError(
                 f"cloud has {cloud.shape[0]} points, config wants {config.n_points}")
-        points: list[np.ndarray] = [np.asarray(cloud, dtype=np.float64)]
+        cloud = np.asarray(cloud)
+        if not np.issubdtype(cloud.dtype, np.floating):
+            cloud = cloud.astype(np.float64)
+        points: list[np.ndarray] = [cloud]
         centers: list[np.ndarray | None] = [None]
         neighbors: list[np.ndarray | None] = [None]
         for spec in config.layers:
             prev = points[-1]
             c = farthest_point_sample_np(prev, spec.n_centers)
-            nb = knn_np(prev[c], prev, spec.n_neighbors)
+            nb = _receptive_fields(spec, prev, c)
             points.append(prev[c])
             centers.append(c)
             neighbors.append(nb)

@@ -86,6 +86,7 @@ via ``dplan=`` — the serving plan cache's handle for skipping
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import inspect
@@ -101,7 +102,8 @@ from repro.core.schedule import (DevicePlan, ExecutionPlan,
                                  GREEDY_DENSE_LIMIT, MODE_PRESETS,
                                  build_plan, complete_order,
                                  device_build_plan, inverse_permutation)
-from repro.core.workload import PointNetConfig, PointNetWorkload
+from repro.core.workload import (PointNetConfig, PointNetWorkload, mlp_label,
+                                 select_mlp)
 from repro.kernels import (aggregate_diff, aggregate_diff_batched,
                            count_dma_elisions, plan_fused_mlp, reram_linear,
                            reram_mlp_fused, reram_mlp_fused_batched)
@@ -151,13 +153,32 @@ def available_backends() -> list[str]:
 
 
 # ---------------------------------------------------------------------------
+# the model's MLPs, by key (``PointNetConfig.mlp_keys``)
+# ---------------------------------------------------------------------------
+
+def _mlp_rows(config: PointNetConfig, key) -> int:
+    """Rows one cloud sends through MLP ``key``."""
+    if key == "head":
+        return 1
+    if key == "sa_all":
+        return config.layers[-1].n_centers
+    spec = config.layers[key[1]]
+    if len(key) == 2:
+        return spec.n_centers * spec.n_neighbors
+    return spec.n_centers * spec.branches[key[2]].n_neighbors
+
+
+# ---------------------------------------------------------------------------
 # backends: how one MLP is applied
 # ---------------------------------------------------------------------------
 
 class Backend:
     """One way of running the model's MLPs. ``key`` addresses an MLP:
     ``("sa", i)`` for SA layer i's 3-stage MLP, ``"head"`` for the
-    classification head. ``apply_mlp`` must accept any leading dims on
+    classification head (``("sa", i, b)`` for branch b of a multi-scale
+    layer, ``"sa_all"`` for the group-all layer;
+    ``PointNetConfig.mlp_keys``).
+    ``apply_mlp`` must accept any leading dims on
     ``x``; ``apply_mlp_batched`` additionally treats axis 0 as a batch of
     independent clouds (backends with ``batched_in_grid = True`` fold it
     into one kernel launch and are never vmapped over)."""
@@ -176,8 +197,7 @@ class Backend:
         self.config = config
 
     def _mlp_params(self, key):
-        return (self.params["head"] if key == "head"
-                else self.params["sa"][key[1]])
+        return select_mlp(self.params, key)
 
     def apply_mlp(self, key, x, *, final_relu: bool = True):
         raise NotImplementedError
@@ -227,7 +247,8 @@ class ReramPerLayerBackend(FloatBackend):
         # Site-keyed injection: the counter restarts at 0 for every
         # apply_mlp call (traced or eager), so layer i of MLP `key`
         # always draws from fold_in(seed, mlp_ix, i) — retrace-stable.
-        mlp_ix = 0 if key == "head" else key[1] + 1
+        mlp_ix = (0 if key == "head"
+                  else self.config.mlp_keys().index(key) + 1)
         layer_ix = itertools.count()
         mm = lambda a, w: reram_linear(
             a, w, fault_model=fm,
@@ -280,8 +301,7 @@ class ReramFusedBackend(Backend):
         self._plan_cache: dict = {}
 
     def _prog(self, key):
-        return (self.program["head"] if key == "head"
-                else self.program["sa"][key[1]])
+        return select_mlp(self.program, key)
 
     def _fused_plan(self, key, m_rows: int):
         """The launch geometry for MLP ``key`` at ``m_rows`` activation
@@ -311,15 +331,12 @@ class ReramFusedBackend(Backend):
             block_n=fp.block_n, block_k=fp.block_k)
 
     def stats(self) -> dict:
-        progs = {f"sa{i}": p for i, p in enumerate(self.program["sa"])}
-        progs["head"] = self.program["head"]
+        keys = self.config.mlp_keys()
+        progs = {mlp_label(k): self._prog(k) for k in keys}
         nbytes = {k: sum(l.nbytes for l in jax.tree_util.tree_leaves(p))
                   for k, p in progs.items()}
-        plans = {}
-        for i, spec in enumerate(self.config.layers):
-            rows = spec.n_centers * spec.n_neighbors
-            plans[f"sa{i}"] = self._plan_row(("sa", i), rows)
-        plans["head"] = self._plan_row("head", 1)
+        plans = {mlp_label(k): self._plan_row(k, _mlp_rows(self.config, k))
+                 for k in keys}
         out = {"program_bytes": sum(nbytes.values()),
                "program_bytes_per_mlp": nbytes,
                "fused_plan": plans}
@@ -665,8 +682,8 @@ class CompiledModel:
         dma = None
         if cloud is not None or workload is not None:
             if workload is None:
-                workload = PointNetWorkload.build(
-                    np.asarray(cloud, np.float64), self.config)
+                workload = PointNetWorkload.build(     # the device's dtype
+                    np.asarray(cloud, np.float32), self.config)
             if self._plan is not None:
                 plan = self._plan
             elif self._dplan is not None:
@@ -726,35 +743,65 @@ class CompiledModel:
         ``pointnet2.forward``. ``n_valid`` masks layer-0 pad rows (the
         bucketing contract); only the first SA layer ever sees them."""
         cfg = self.config
-        feats = _pn.lift_features(cloud, cfg.layers[0].in_features)
+        feats = _pn.input_features(cfg, cloud)
         pts = cloud
         for i, spec in enumerate(cfg.layers):
-            pts, diff = _pn._sa_geometry(spec, pts, feats,
-                                         n_valid if i == 0 else None)
-            h = self.backend.apply_mlp(("sa", i), diff)
-            feats = jnp.max(h, axis=1)                   # reduction over K
-        g = jnp.max(feats, axis=0)                       # global max pool
-        return self.backend.apply_mlp("head", g, final_relu=False)
+            pts, diffs = _pn.sa_group(spec, pts, feats,
+                                      n_valid if i == 0 else None)
+            feats = self._reduce_branches(i, diffs, axis=1)
+        return self._classify(pts, feats)
 
     def _batched_in_grid(self, clouds, n_valid=None):
         """Batch-in-grid execution: vmap only the per-cloud geometry; every
         MLP is ONE batched kernel launch (never vmap over the kernel)."""
         cfg = self.config
-        feats = jax.vmap(
-            lambda c: _pn.lift_features(c, cfg.layers[0].in_features))(clouds)
+        feats = jax.vmap(functools.partial(_pn.input_features, cfg))(clouds)
         pts = clouds
         for i, spec in enumerate(cfg.layers):
             if i == 0 and n_valid is not None:
-                pts, diff = jax.vmap(
-                    functools.partial(_pn._sa_geometry, spec))(pts, feats,
-                                                               n_valid)
+                pts, diffs = jax.vmap(
+                    functools.partial(_pn.sa_group, spec))(pts, feats,
+                                                           n_valid)
             else:
-                pts, diff = jax.vmap(
-                    functools.partial(_pn._sa_geometry, spec))(pts, feats)
-            h = self.backend.apply_mlp_batched(("sa", i), diff)
-            feats = jnp.max(h, axis=2)                   # reduction over K
-        g = jnp.max(feats, axis=1)                       # global max pool
-        return self.backend.apply_mlp_batched("head", g, final_relu=False)
+                pts, diffs = jax.vmap(
+                    functools.partial(_pn.sa_group, spec))(pts, feats)
+            feats = self._reduce_branches(i, diffs, axis=2, batched=True)
+        return self._classify_batched(pts, feats)
+
+    def _reduce_branches(self, i, diffs, *, axis, batched=False):
+        """SA layer ``i``'s feature computation and reduction on its
+        grouped rows: per branch the MLP and the max over its K rows, the
+        branches concatenated (one branch: its output)."""
+        apply = (self._apply_mlp_batched if batched
+                 else self.backend.apply_mlp)
+        outs = [jnp.max(apply(key, d), axis=axis)       # reduction over K
+                for key, d in zip(self.config.layer_mlp_keys(i), diffs)]
+        return outs[0] if len(outs) == 1 else jnp.concatenate(outs, -1)
+
+    def _group_all(self, pts, feats, *, batched=False):
+        """The group-all layer: its MLP over every point's ``[p_j ; f_j]``
+        and the max over the points, keeping that axis as one point."""
+        apply = (self._apply_mlp_batched if batched
+                 else self.backend.apply_mlp)
+        h = apply("sa_all", jnp.concatenate([pts, feats], axis=-1))
+        return jnp.max(h, axis=-2, keepdims=True)
+
+    def _classify(self, pts, feats):
+        """One cloud's last points and features -> logits: the group-all
+        layer where there is one, the global max pool, the head."""
+        if self.config.group_all is not None:
+            with jax.named_scope("sa_all"):
+                feats = self._group_all(pts, feats)
+        g = jnp.max(feats, axis=0)                       # global max pool
+        return self.backend.apply_mlp("head", g, final_relu=False)
+
+    def _classify_batched(self, pts, feats):
+        """:meth:`_classify` over a batch."""
+        if self.config.group_all is not None:
+            with jax.named_scope("sa_all"):
+                feats = self._group_all(pts, feats, batched=True)
+        with jax.named_scope("head"):
+            return self._head_batched(feats)
 
     def _geometry_pass(self, cloud, n_valid=None):
         """Pass 1 of planned execution: the same FPS/kNN geometry as the
@@ -796,7 +843,7 @@ class CompiledModel:
         host transfers), or — host fallback — lowered here from the host
         plan the spec/policy builds for this cloud's geometry."""
         cfg = self.config
-        feats = _pn.lift_features(cloud, cfg.layers[0].in_features)
+        feats = _pn.input_features(cfg, cloud)
         pts_list, ctr_list, nbr_list = self._geometry_pass(cloud, n_valid)
         if dplan is not None:
             pass                              # caller-supplied (plan cache)
@@ -816,20 +863,28 @@ class CompiledModel:
                    and not isinstance(cloud, jax.core.Tracer))
         streams = []
         for k in range(1, cfg.n_layers + 1):
+            spec = cfg.layers[k - 1]
             order = dplan.order_of(k)
             inv = dplan.inverse_of(k)
             nbr_o = jnp.take(nbr_list[k].astype(jnp.int32), order, axis=0)
             ctr_o = jnp.take(ctr_list[k].astype(jnp.int32), order, axis=0)
             if collect:
                 streams.append([np.asarray(nbr_o)])
-            diff = aggregate_diff(feats, nbr_o, ctr_o)   # plan-ordered gather
-            h = self.backend.apply_mlp(("sa", k - 1), diff)
-            out = jnp.max(h, axis=1)                     # reduction over K
+            rows, ctrs = _pn.grouping_operands(spec, pts_list[k - 1], feats)
+            diffs = [aggregate_diff(rows, self._branch(nbr_o, lo, hi),
+                                    ctr_o, ctrs)    # plan-ordered gather
+                     for lo, hi in _pn.branch_bounds(spec)]
+            out = self._reduce_branches(k - 1, diffs, axis=1)
             feats = jnp.take(out, inv, axis=0)           # back to index order
         if collect:
             self._last_dma = self._dma_report(None, None, 72, streams=streams)
-        g = jnp.max(feats, axis=0)
-        return self.backend.apply_mlp("head", g, final_relu=False)
+        return self._classify(pts_list[-1], feats)
+
+    @staticmethod
+    def _branch(nbr, lo, hi):
+        """Columns ``lo:hi`` (one branch) of plan-ordered receptive fields;
+        the whole array when they are all of it."""
+        return nbr if (lo, hi) == (0, nbr.shape[-1]) else nbr[..., lo:hi]
 
     def _batched_forward_planned(self, clouds, n_valid=None, dplan=None):
         """Batched plan-driven execution — the per-cloud Python loop folded
@@ -858,26 +913,20 @@ class CompiledModel:
                      for b in range(batch)]
         dplan = self._device_plan_for(*geoms[0], batch_geoms=geoms)
         tracing = isinstance(clouds, jax.core.Tracer)
-        feats = jnp.stack([_pn.lift_features(clouds[b],
-                                             cfg.layers[0].in_features)
+        feats = jnp.stack([_pn.input_features(cfg, clouds[b])
                            for b in range(batch)])
-        streams = []
+        pts = clouds
+        streams = None if tracing else []
         for k in range(1, cfg.n_layers + 1):
-            order = dplan.order_of(k)
-            inv = dplan.inverse_of(k)
-            nbr_k = jnp.stack([g[2][k] for g in geoms]).astype(jnp.int32)
-            ctr_k = jnp.stack([g[1][k] for g in geoms]).astype(jnp.int32)
-            nbr_o = jnp.take_along_axis(nbr_k, order[:, :, None], axis=1)
-            ctr_o = jnp.take_along_axis(ctr_k, order, axis=1)
-            if not tracing:
-                streams.append(list(np.asarray(nbr_o)))
-            diff = aggregate_diff_batched(feats, nbr_o, ctr_o)  # ONE launch
-            h = self._apply_sa_mlp_batched(k, diff)
-            out = jnp.max(h, axis=2)                     # reduction over K
-            feats = jnp.take_along_axis(out, inv[:, :, None], axis=1)
+            nbr_k = jnp.stack([g[2][k] for g in geoms])
+            ctr_k = jnp.stack([g[1][k] for g in geoms])
+            feats = self._sa_layer_batched(
+                k, pts, feats, nbr_k, ctr_k, dplan.order_of(k),
+                dplan.inverse_of(k), streams=streams)
+            pts = jnp.stack([g[0][k] for g in geoms])
         if not tracing:
             self._last_dma = self._dma_report(None, None, 72, streams=streams)
-        return self._head_batched(feats)
+        return self._classify_batched(pts, feats)
 
     def _batched_forward_device(self, clouds, n_valid=None, dplan=None):
         """The fully-traced batched path: vmapped geometry, a vmapped
@@ -894,8 +943,7 @@ class CompiledModel:
         order), the classifier's under ``head``."""
         cfg = self.config
         batch = clouds.shape[0]
-        feats = jax.vmap(
-            lambda c: _pn.lift_features(c, cfg.layers[0].in_features))(clouds)
+        feats = jax.vmap(functools.partial(_pn.input_features, cfg))(clouds)
         if n_valid is None:
             pts_s, ctr_s, nbr_s = jax.vmap(
                 functools.partial(_pn.geometry_pass, cfg))(clouds)
@@ -923,31 +971,54 @@ class CompiledModel:
             if not dplan.batched:                 # one plan shared batch-wide
                 order = jnp.broadcast_to(order, (batch,) + order.shape)
                 inv = jnp.broadcast_to(inv, (batch,) + inv.shape)
-            with jax.named_scope("gather"):
-                nbr_o = jnp.take_along_axis(nbr_s[k].astype(jnp.int32),
-                                            order[:, :, None], axis=1)
-                ctr_o = jnp.take_along_axis(ctr_s[k].astype(jnp.int32),
-                                            order, axis=1)
-                diff = aggregate_diff_batched(feats, nbr_o, ctr_o)
-            with jax.named_scope("mlp"):
-                h = self._apply_sa_mlp_batched(k, diff)
-                out = jnp.max(h, axis=2)                 # reduction over K
-                feats = jnp.take_along_axis(out, inv[:, :, None], axis=1)
-        with jax.named_scope("head"):
-            return self._head_batched(feats)
+            feats = self._sa_layer_batched(k, pts_s[k - 1], feats, nbr_s[k],
+                                           ctr_s[k], order, inv)
+        return self._classify_batched(pts_s[-1], feats)
 
-    def _apply_sa_mlp_batched(self, k, diff):
+    def _sa_layer_batched(self, k, pts, feats, nbr, ctr, order, inv, *,
+                          streams=None):
+        """SA layer ``k`` (1-based) over a batch, in plan order: the
+        plan-ordered gather (one ``aggregate_diff_batched`` per branch),
+        each branch's MLP and max over K, the branches concatenated and
+        scattered back to index order. ``pts``/``feats`` are layer k-1's;
+        ``streams`` collects the plan-ordered index streams where given.
+        Ops sit under the named scopes ``gather`` and ``mlp``, a
+        multi-scale layer's branch b under ``gather/branch<b>`` and
+        ``mlp/branch<b>``."""
+        spec = self.config.layers[k - 1]
+        with jax.named_scope("gather"):
+            nbr_o = jnp.take_along_axis(nbr.astype(jnp.int32),
+                                        order[:, :, None], axis=1)
+            ctr_o = jnp.take_along_axis(ctr.astype(jnp.int32), order,
+                                        axis=1)
+            if streams is not None:
+                streams.append(list(np.asarray(nbr_o)))
+            rows, ctrs = _pn.grouping_operands(spec, pts, feats)
+        outs = []
+        for key, (lo, hi) in zip(self.config.layer_mlp_keys(k - 1),
+                                 _pn.branch_bounds(spec)):
+            branch = (lambda: jax.named_scope(f"branch{key[2]}")) \
+                if len(key) > 2 else contextlib.nullcontext
+            with jax.named_scope("gather"), branch():
+                diff = aggregate_diff_batched(
+                    rows, self._branch(nbr_o, lo, hi), ctr_o, ctrs)
+            with jax.named_scope("mlp"), branch():
+                h = self._apply_mlp_batched(key, diff)
+                outs.append(jnp.max(h, axis=2))          # reduction over K
+        with jax.named_scope("mlp"):
+            out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, -1)
+            return jnp.take_along_axis(out, inv[:, :, None], axis=1)
+
+    def _apply_mlp_batched(self, key, x, *, final_relu=True):
         if self.backend.batched_in_grid:
-            return self.backend.apply_mlp_batched(("sa", k - 1), diff)
-        return jax.vmap(
-            lambda d, key=("sa", k - 1): self.backend.apply_mlp(key, d))(diff)
+            return self.backend.apply_mlp_batched(key, x,
+                                                  final_relu=final_relu)
+        return jax.vmap(lambda v: self.backend.apply_mlp(
+            key, v, final_relu=final_relu))(x)
 
     def _head_batched(self, feats):
         g = jnp.max(feats, axis=1)                       # global max pool
-        if self.backend.batched_in_grid:
-            return self.backend.apply_mlp_batched("head", g, final_relu=False)
-        return jax.vmap(
-            lambda v: self.backend.apply_mlp("head", v, final_relu=False))(g)
+        return self._apply_mlp_batched("head", g, final_relu=False)
 
     def _host_plan_for(self, pts_list, ctr_list, nbr_list) -> ExecutionPlan:
         """Build the host ``ExecutionPlan`` for one cloud's geometry via

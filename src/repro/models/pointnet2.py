@@ -8,7 +8,11 @@ described in Fig. 1:
                     feature computation  M(D(...))          (3-stage MLP)
                     reduction            column-wise max over neighbors
 
-plus a classification head for the end-to-end training example. The
+plus a classification head for the end-to-end training example, and
+PointNet++'s multi-scale grouping (``MSGLayerSpec``): per branch a ball
+query (:func:`ball_query`) and its own MLP over the grouped rows
+``[p_j - p_i ; f_j]``, the branches concatenated, then a group-all layer
+over ``[p_j ; f_j]``. The
 geometry functions are the JAX twins of the NumPy ones in
 ``repro.core.workload`` (cross-checked in tests); this module is what the
 dry-run/trainer lower, while ``repro.core`` is what the accelerator
@@ -37,7 +41,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.workload import PointNetConfig, SALayerSpec
+from repro.core.workload import (MSGLayerSpec, PointNetConfig, SALayerSpec,
+                                 map_mlps)
 from repro.kernels import build_program
 
 Params = Any
@@ -99,6 +104,33 @@ def knn(queries: jnp.ndarray, points: jnp.ndarray, k: int, *,
     return idx
 
 
+def ball_query(queries: jnp.ndarray, points: jnp.ndarray, radius: float,
+               k: int, *, n_valid=None) -> jnp.ndarray:
+    """(Q, k) int32: per query the first ``k`` points in index order whose
+    squared distance is below ``radius**2``, the slots past the last hit
+    filled with the first hit (the reference code's ``query_ball_point``);
+    identical to ``core.workload.ball_query_np`` on the same coordinates.
+    Squared distances accumulate x, y, z in that order, and ``radius**2``
+    is formed in the points' dtype, as on the host.
+
+    ``n_valid`` masks trailing pad rows out of every ball, so a padded
+    cloud selects exactly what the unpadded one does. A query that is a
+    member of ``points`` is its own first hit, so every slot is filled."""
+    n = points.shape[0]
+    r = jnp.asarray(radius, points.dtype)
+    d = (queries[:, None, 0] - points[None, :, 0]) ** 2
+    for c in range(1, points.shape[1]):
+        d = d + (queries[:, None, c] - points[None, :, c]) ** 2
+    hit = d < r * r
+    if n_valid is not None:
+        hit = hit & (jnp.arange(n)[None, :] < n_valid)
+    # a hit's key is its index, a miss's is n: the k smallest keys are
+    # the first k hits in index order, then misses
+    key = jnp.where(hit, jnp.arange(n, dtype=jnp.int32)[None, :], n)
+    first = -jax.lax.top_k(-key, k)[0]
+    return jnp.where(first == n, first[:, :1], first)
+
+
 # ---------------------------------------------------------------------------
 # parameters
 # ---------------------------------------------------------------------------
@@ -114,27 +146,40 @@ def _init_mlp(key, widths: tuple[int, ...], dtype=jnp.float32):
 
 def init_params(key, config: PointNetConfig, n_classes: int = 40,
                 dtype=jnp.float32) -> Params:
+    """He-normal weights and zero biases: ``sa[i]`` is layer i's MLP (a
+    list of ``{"w", "b"}``), or for a multi-scale layer one such MLP per
+    branch; ``sa_all`` the group-all MLP where the config has one;
+    ``head`` the classifier."""
     keys = jax.random.split(key, config.n_layers + 1)
-    sa = [_init_mlp(k, spec.mlp, dtype)
-          for k, spec in zip(keys[:-1], config.layers)]
-    d_last = config.layers[-1].out_features
-    head = _init_mlp(keys[-1], (d_last, 256, n_classes), dtype)
-    return {"sa": sa, "head": head}
+    sa = []
+    for k, spec in zip(keys[:-1], config.layers):
+        if isinstance(spec, MSGLayerSpec):
+            sa.append([_init_mlp(kb, b.mlp, dtype) for kb, b in zip(
+                jax.random.split(k, len(spec.branches)), spec.branches)])
+        else:
+            sa.append(_init_mlp(k, spec.mlp, dtype))
+    head = _init_mlp(keys[-1], (config.out_features, *config.head,
+                                n_classes), dtype)
+    params = {"sa": sa, "head": head}
+    if config.group_all is not None:
+        params["sa_all"] = _init_mlp(jax.random.fold_in(keys[-1], 1),
+                                     config.group_all.mlp, dtype)
+    return params
 
 
 def build_model_program(params: Params, *, ecc=None) -> dict:
     """Program every MLP of the model into crossbars ('reram-fused'
-    backend): one :class:`~repro.kernels.CrossbarProgram` per SA layer plus
-    one for the classification head. Weights are quantized and
-    plane-encoded here, exactly once — pass the result to
+    backend): one :class:`~repro.kernels.CrossbarProgram` per SA layer —
+    per branch of a multi-scale layer — plus one for the group-all layer
+    where there is one and one for the classification head. Weights are
+    quantized and plane-encoded here, exactly once — pass the result to
     ``forward``/``batched_forward`` and the per-forward hot path never
     touches ``encode_planes``/``quantize_tensor`` on weights again.
 
     ``ecc`` (an :class:`repro.reliability.EccConfig`, or True for the
     default) Hamming-protects every program's spare columns at build time
     (DESIGN.md §13); MVM results are unchanged."""
-    return {"sa": [build_program(mlp, ecc=ecc) for mlp in params["sa"]],
-            "head": build_program(params["head"], ecc=ecc)}
+    return map_mlps(lambda _, mlp: build_program(mlp, ecc=ecc), params)
 
 
 # ---------------------------------------------------------------------------
@@ -162,6 +207,46 @@ def lift_features(points: jnp.ndarray, n_features: int) -> jnp.ndarray:
     return f[:, :n_features]
 
 
+def point_normals(points: jnp.ndarray) -> jnp.ndarray:
+    """The 3 normal channels a multi-scale model's first layer takes,
+    stood in by each point's direction from the centre,
+    ``p / max(|p|, 1e-6)``: the synthetic objects are centred and carry
+    no normals."""
+    norm = jnp.sqrt(jnp.sum(points * points, axis=-1, keepdims=True))
+    return points / jnp.maximum(norm, 1e-6)
+
+
+def input_features(config: PointNetConfig, cloud: jnp.ndarray):
+    """Layer-0 features of one cloud: the stand-in normals for a
+    multi-scale first layer, else :func:`lift_features`."""
+    first = config.layers[0]
+    if isinstance(first, MSGLayerSpec):
+        return point_normals(cloud)
+    return lift_features(cloud, first.in_features)
+
+
+def branch_bounds(spec) -> list[tuple[int, int]]:
+    """Column range of each branch in a layer's concatenated receptive
+    fields: one range for a single-scale layer."""
+    if not isinstance(spec, MSGLayerSpec):
+        return [(0, spec.n_neighbors)]
+    ends = np.cumsum([0] + [b.n_neighbors for b in spec.branches])
+    return [(int(a), int(b)) for a, b in zip(ends[:-1], ends[1:])]
+
+
+def grouping_operands(spec, points, features):
+    """``(rows, centres)``: what a layer's gather reads at a neighbour
+    and subtracts at the centre. Single-scale: ``F_j - F_i`` on the
+    features alike (``centres`` None: the rows themselves); multi-scale:
+    ``[p_j ; f_j] - [p_i ; 0]``, the grouped row ``[p_j - p_i ; f_j]``.
+    Leading batch dims pass through."""
+    if not isinstance(spec, MSGLayerSpec):
+        return features, None
+    rows = jnp.concatenate([points, features], axis=-1)
+    ctrs = jnp.concatenate([points, jnp.zeros_like(features)], axis=-1)
+    return rows, ctrs
+
+
 def geometry_pass(config: PointNetConfig, cloud: jnp.ndarray, *,
                   n_valid=None):
     """The full FPS/kNN geometry of every SA layer on one cloud, as
@@ -169,7 +254,9 @@ def geometry_pass(config: PointNetConfig, cloud: jnp.ndarray, *,
     FPS-selected coordinates ``pts[k]`` (n_k, 3), center indices
     ``ctr[k]`` (n_k,) into layer k-1, and receptive fields ``nbr[k]``
     (n_k, K) into layer k-1 (index 0 holds the input cloud / None / None,
-    matching :class:`~repro.core.workload.PointNetWorkload` layout).
+    matching :class:`~repro.core.workload.PointNetWorkload` layout). A
+    multi-scale layer's ``nbr[k]`` is its branches' ball queries
+    concatenated, (n_k, sum K_b) (:func:`branch_bounds` splits it).
 
     This is the planning pipeline's input: ``compile_model``'s planned
     execution builds its gather orders from exactly these tensors —
@@ -186,8 +273,8 @@ def geometry_pass(config: PointNetConfig, cloud: jnp.ndarray, *,
     cloud's (the bucketing contract in ``repro.models.backend``).
 
     Its ops sit under the named scopes ``geometry/fps`` and
-    ``geometry/knn``, so a profile tells the two apart wherever the pass
-    is traced."""
+    ``geometry/knn`` (``geometry/ball`` for ball queries), so a profile
+    tells them apart wherever the pass is traced."""
     pts_list, ctr_list, nbr_list = [cloud], [None], [None]
     pts = cloud
     with jax.named_scope("geometry"):
@@ -197,8 +284,15 @@ def geometry_pass(config: PointNetConfig, cloud: jnp.ndarray, *,
                 centers = farthest_point_sample(pts, spec.n_centers,
                                                 n_valid=nv)
                 c_pts = pts[centers]
-            with jax.named_scope("knn"):
-                nbr = knn(c_pts, pts, spec.n_neighbors, n_valid=nv)
+            if isinstance(spec, MSGLayerSpec):
+                with jax.named_scope("ball"):
+                    nbr = jnp.concatenate(
+                        [ball_query(c_pts, pts, b.radius, b.n_neighbors,
+                                    n_valid=nv) for b in spec.branches],
+                        axis=1)
+            else:
+                with jax.named_scope("knn"):
+                    nbr = knn(c_pts, pts, spec.n_neighbors, n_valid=nv)
             pts_list.append(c_pts)
             ctr_list.append(centers)
             nbr_list.append(nbr)
@@ -217,6 +311,23 @@ def _sa_geometry(spec: SALayerSpec, points, features, n_valid=None):
     f_nbr = features[nbr]                               # (M, K, C)
     f_ctr = features[centers][:, None, :]
     return c_pts, f_nbr - f_ctr                         # aggregation D(.)
+
+
+def sa_group(spec, points, features, n_valid=None):
+    """:func:`_sa_geometry` for any layer: the centres' coordinates and a
+    list of grouped-row tensors, one (M, K_b, C) per branch — a single
+    ``F_j - F_i`` entry for a single-scale layer, ``[p_j - p_i ; f_j]``
+    per ball query for a multi-scale one."""
+    if not isinstance(spec, MSGLayerSpec):
+        c_pts, diff = _sa_geometry(spec, points, features, n_valid)
+        return c_pts, [diff]
+    centers = farthest_point_sample(points, spec.n_centers, n_valid=n_valid)
+    c_pts = points[centers]
+    rows, ctrs = grouping_operands(spec, points, features)
+    c_rows = ctrs[centers][:, None, :]
+    return c_pts, [rows[ball_query(c_pts, points, b.radius, b.n_neighbors,
+                                   n_valid=n_valid)] - c_rows
+                   for b in spec.branches]
 
 
 def sa_layer(mlp_params, spec: SALayerSpec, points, features):

@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.energy import DEFAULT_HW, HWParams
+from repro.core.workload import map_mlps
 from repro.kernels.program import CROSSBAR, CrossbarProgram, _pad2
 
 __all__ = [
@@ -244,9 +245,9 @@ def correct_program(program: CrossbarProgram) -> CrossbarProgram:
 def correct_model_program(programs: dict) -> dict:
     """Scrub a whole-model program dict; programs without an ECC spec
     pass through unchanged (nothing to correct)."""
-    fix = lambda p: correct_program(p) if p.ecc is not None else p
-    return {"sa": [fix(p) for p in programs["sa"]],
-            "head": fix(programs["head"])}
+    return map_mlps(
+        lambda _, p: correct_program(p) if p.ecc is not None else p,
+        programs)
 
 
 def ecc_overhead(program: CrossbarProgram,

@@ -41,6 +41,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from repro.core.workload import map_mlps
+
 __all__ = ["FaultModel"]
 
 
@@ -150,12 +152,13 @@ class FaultModel:
     def apply_model_program(self, programs: dict,
                             key: jax.Array | None = None) -> dict:
         """Inject into a whole-model program dict (the
-        ``{"sa": [...], "head": ...}`` layout of
+        ``{"sa": [...], "sa_all", "head": ...}`` layout of
         :func:`repro.models.pointnet2.build_model_program`), folding a
-        distinct subkey per MLP so faults are independent across MLPs."""
+        distinct subkey per MLP — its seed index
+        (:func:`repro.core.workload.map_mlps`) — so faults are independent
+        across MLPs."""
         if key is None:
             key = self.base_key()
-        sa = [self.apply(p, jax.random.fold_in(key, i + 1))
-              for i, p in enumerate(programs["sa"])]
-        head = self.apply(programs["head"], jax.random.fold_in(key, 0))
-        return {"sa": sa, "head": head}
+        return map_mlps(
+            lambda ix, p: self.apply(p, jax.random.fold_in(key, ix)),
+            programs)

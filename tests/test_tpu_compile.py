@@ -19,11 +19,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.workload import PAPER_MODELS
+from repro.core.workload import PAPER_MODELS, POINTNET2_MSG
 from repro.kernels import (aggregate_diff, aggregate_diff_batched,
                            build_program, fps_update, plan_fused_mlp,
                            requantize, reram_matmul_int,
                            reram_mlp_fused_batched)
+from repro.kernels.aggregate import index_chunks
 
 BATCH = 4          # the serving tier's largest batch bucket in chip_smoke
 
@@ -77,6 +78,47 @@ def test_gather_compiles(on_tpu, model, layer):
              _spec(on_tpu, (BATCH, spec.n_centers, spec.n_neighbors),
                    jnp.int32),
              _spec(on_tpu, (BATCH, spec.n_centers), jnp.int32))
+
+
+def test_gather_compiles_chunked_at_msg_widths(on_tpu):
+    """MSG SA-1's K = 128 branch at the serving tier's largest batch of 16:
+    its index stream (16 x 512 x 129 words, 4 MiB) is past the v5e's SMEM,
+    so it runs as several launches, each compiled here, with the centre
+    rows ``[p ; 0]`` as an operand of their own."""
+    spec = POINTNET2_MSG.layers[0]
+    c, k = 3 + spec.in_features, spec.branches[-1].n_neighbors
+    rows = _spec(on_tpu, (16, POINTNET2_MSG.n_points, c), jnp.float32)
+    text = _compile(aggregate_diff_batched, rows,
+                    _spec(on_tpu, (16, spec.n_centers, k), jnp.int32),
+                    _spec(on_tpu, (16, spec.n_centers), jnp.int32), rows)
+    assert text.count("tpu_custom_call") == len(
+        index_chunks(16, spec.n_centers, k)) > 1
+
+
+def _msg_mlps():
+    """(id, widths, rows per cloud) of every MLP of the MSG classifier."""
+    cfg = POINTNET2_MSG
+    out = [(f"sa{i}.b{b}-k{br.n_neighbors}", br.mlp,
+            spec.n_centers * br.n_neighbors)
+           for i, spec in enumerate(cfg.layers)
+           for b, br in enumerate(spec.branches)]
+    return out + [("sa_all", cfg.group_all.mlp, cfg.layers[-1].n_centers),
+                  ("head", (cfg.out_features, *cfg.head, 40), 1)]
+
+
+@pytest.mark.parametrize("widths,rows", [m[1:] for m in _msg_mlps()],
+                         ids=[m[0] for m in _msg_mlps()])
+def test_fused_mlp_compiles_at_msg_widths(on_tpu, widths, rows):
+    """The dataflow ``plan_fused_mlp`` picks for each MSG MLP at its rows
+    per cloud compiles at batch 16."""
+    layers = [{"w": jnp.zeros((k, n), jnp.float32),
+               "b": jnp.zeros((n,), jnp.float32)}
+              for k, n in zip(widths[:-1], widths[1:])]
+    prog = jax.eval_shape(build_program, layers)
+    mode = plan_fused_mlp(prog, rows).mode
+    prog = jax.tree.map(lambda a: _spec(on_tpu, a.shape, a.dtype), prog)
+    x = _spec(on_tpu, (16, rows, widths[0]), jnp.float32)
+    _compile(lambda x, p: reram_mlp_fused_batched(x, p, mode=mode), x, prog)
 
 
 @pytest.mark.parametrize("model,layer,mode,picked", [
