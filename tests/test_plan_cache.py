@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 from repro.core.schedule import DevicePlan, PlanCache, cloud_content_key
-from repro.core.workload import PointNetConfig, SALayerSpec
+from repro.core.workload import (BallBranch, GroupAllSpec, MSGLayerSpec,
+                                 PointNetConfig, SALayerSpec)
 from repro.data.pointcloud import request_stream
 from repro.launch.serve import PointCloudServable, ServingEngine, ShapeBuckets
 from repro.models import pointnet2 as pn
@@ -20,6 +21,17 @@ def tiny_config(n=64, c1=24, c2=8, k=4):
         SALayerSpec(n_centers=c2, n_neighbors=k, in_features=16,
                     mlp=(16, 16, 16, 32)),
     ))
+
+
+def tiny_msg(n=64):
+    return PointNetConfig(name="tiny-cache-msg", n_points=n, layers=(
+        MSGLayerSpec(n_centers=24, in_features=3, branches=(
+            BallBranch(0.3, 4, (6, 8, 8, 16)),
+            BallBranch(0.6, 8, (6, 8, 16)))),
+        MSGLayerSpec(n_centers=8, in_features=32, branches=(
+            BallBranch(0.5, 4, (35, 16, 32)),
+            BallBranch(1.0, 8, (35, 16, 16)))),
+    ), group_all=GroupAllSpec(in_features=48, mlp=(51, 32, 64)), head=(32,))
 
 
 @pytest.fixture(scope="module")
@@ -185,3 +197,126 @@ def test_cache_rejected_for_uncacheable_models(setup):
     # ... but an EXPLICIT cache on an uncacheable model is an error
     with pytest.raises(ValueError, match="no .*plan to cache"):
         PointCloudServable(baseline, plan_cache=PlanCache())
+
+
+# ---------------------------------------------------------------------------
+# the serving step stacks the batch's plans in its own program
+# ---------------------------------------------------------------------------
+
+#: batches served in this order through one bucket of four: each cloud's
+#: point count and seed; seeds already served are plan-cache hits
+_BATCHES = {
+    "misses": ((64, 10), (40, 11), (56, 12), (64, 13)),
+    "hits": ((64, 13), (64, 10), (56, 12), (40, 11)),
+    "mixed": ((64, 10), (48, 14), (56, 12), (64, 15)),
+    "pads": ((48, 16), (64, 13), (44, 17)),           # b_real 3 of 4
+}
+
+
+def _unit_cloud(n, seed):
+    """A cloud inside the unit ball, so the MSG balls hold neighbours."""
+    c = _cloud(n, seed)
+    return c / np.linalg.norm(c, axis=1).max()
+
+
+@pytest.fixture(scope="module", params=["ssg", "msg"])
+def served_batches(request):
+    """Serve :data:`_BATCHES` in order through one servable, counting
+    :meth:`DevicePlan.stack` calls made on concrete arrays (outside any
+    trace) and the step's traces after each batch. Returns the model, the
+    per-batch results, the eager stack calls and the trace counts."""
+    cfg = tiny_config() if request.param == "ssg" else tiny_msg()
+    params = pn.init_params(jax.random.PRNGKey(2), cfg, n_classes=10)
+    model = compile_model(params, cfg, backend="reram-fused",
+                          schedule="pointer")
+    servable = PointCloudServable(
+        model, buckets=ShapeBuckets(points=(64,), batch=(4,)))
+    engine = ServingEngine(servable)
+    stack = DevicePlan.__dict__["stack"].__func__
+    eager = []
+
+    def counting_stack(cls, plans):
+        plans = list(plans)
+        if not isinstance(plans[0].orders[0], jax.core.Tracer):
+            eager.append(len(plans))
+        return stack(cls, plans)
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(DevicePlan, "stack", classmethod(counting_stack))
+    served, traces, misses = {}, {}, {}
+    try:
+        for case, batch in _BATCHES.items():
+            clouds = [_unit_cloud(n, seed) for n, seed in batch]
+            m0 = servable.plan_cache.misses
+            reqs = [engine.submit(c) for c in clouds]
+            assert len(engine.step()) == len(clouds)   # one batch
+            served[case] = (clouds, [np.asarray(r.result) for r in reqs])
+            traces[case] = servable.jit_traces
+            misses[case] = servable.plan_cache.misses - m0
+    finally:
+        mp.undo()
+    return model, served, eager, traces, misses
+
+
+@pytest.mark.parametrize("case", list(_BATCHES))
+def test_served_logits_equal_the_stacked_plan_forward(served_batches, case):
+    """Each served row equals ``batched_forward`` over the same padded
+    batch with ``DevicePlan.stack`` of the rows' own plans (the pads
+    reusing row 0's), bitwise."""
+    model, served, _, _, misses = served_batches
+    assert misses[case] == {"misses": 4, "hits": 0, "mixed": 2,
+                            "pads": 2}[case]
+    clouds, got = served[case]
+    padded = np.zeros((4, 64, 3), np.float32)
+    n_valid = np.empty((4,), np.int32)
+    for i, c in enumerate(clouds):
+        padded[i, :len(c)] = c
+        n_valid[i] = len(c)
+    padded[len(clouds):] = padded[0]
+    n_valid[len(clouds):] = n_valid[0]
+    build = jax.jit(lambda c, nv: model.build_device_plan(c, n_valid=nv))
+    plans = [build(jnp.asarray(padded[i]), jnp.int32(n_valid[i]))
+             for i in range(len(clouds))]
+    plans += [plans[0]] * (4 - len(clouds))
+    ref = np.asarray(model.batched_forward(
+        jnp.asarray(padded), n_valid=jnp.asarray(n_valid),
+        dplan=DevicePlan.stack(plans)))
+    for i, row in enumerate(got):
+        np.testing.assert_array_equal(row, ref[i])
+
+
+def test_step_stacks_plans_in_trace_once_per_bucket(served_batches):
+    """``run_batch`` makes no eager ``DevicePlan.stack`` call, and the step
+    traces once for the bucket across miss, hit, mixed and padded
+    batches."""
+    _, _, eager, traces, _ = served_batches
+    assert eager == []
+    assert set(traces.values()) == {1}
+
+
+def test_policy_plans_of_mixed_intra_trace_the_step_once():
+    """A multi-candidate ``PlanPolicy`` plans on the host and picks
+    ``intra`` per cloud; batches whose clouds chose different orderings
+    (row 0's included) still trace the step once for the bucket, and each
+    served row equals the model's own forward on that cloud."""
+    from repro.core.policy import PlanPolicy
+    cfg = tiny_config()
+    params = pn.init_params(jax.random.PRNGKey(0), cfg, n_classes=10)
+    # a 4-row window makes the candidates' elision counts differ
+    model = compile_model(params, cfg, backend="reram-fused",
+                          policy=PlanPolicy(window=4))
+    assert not model.device_planning
+    servable = PointCloudServable(
+        model, buckets=ShapeBuckets(points=(64,), batch=(4,)))
+    engine = ServingEngine(servable)
+    chosen = set()
+    for seeds in ((0, 3, 9, 1), (3, 9, 0, 4), (9, 5, 6), (7, 8, 2, 3)):
+        clouds = [_cloud(64, s) for s in seeds]
+        reqs = [engine.submit(c) for c in clouds]
+        assert len(engine.step()) == len(clouds)          # one batch
+        chosen |= {model.build_device_plan(c).intra for c in clouds}
+        for c, r in zip(clouds, reqs):
+            np.testing.assert_array_equal(np.asarray(r.result),
+                                          np.asarray(model.forward(jnp.asarray(c))))
+    assert chosen == {"index", "greedy", "morton"}
+    assert servable.jit_traces == 1

@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.schedule import DevicePlan
 from repro.core.workload import PAPER_MODELS, PointNetConfig, SALayerSpec
 from repro.launch.serve import PointCloudServable, ServingEngine, ShapeBuckets
 from repro.models import pointnet2 as pn
@@ -46,7 +45,8 @@ def _module_name(hlo: str) -> str:
 @pytest.fixture(scope="module")
 def model0_programs():
     """The compiled HLO text of model0's plan builder (one cloud) and its
-    serving step (a batch of two, with a stacked plan)."""
+    serving step (a batch of two, handed the list of the two plans it
+    stacks, as ``run_batch`` hands it)."""
     cfg = PAPER_MODELS["model0"]
     params = pn.init_params(jax.random.PRNGKey(0), cfg, n_classes=40)
     model = compile_model(params, cfg, backend="reram-fused",
@@ -57,14 +57,16 @@ def model0_programs():
     nv = jax.ShapeDtypeStruct((), jnp.int32)
     builder = sv._jit_build.lower(cloud, nv).compile().as_text()
     plan = jax.eval_shape(sv._jit_build, cloud, nv)
-    dplan = jax.eval_shape(lambda p: DevicePlan.stack([p, p]), plan)
     step = sv._jit_step.lower(
         jax.ShapeDtypeStruct((2, cfg.n_points, 3), jnp.float32),
-        jax.ShapeDtypeStruct((2,), jnp.int32), dplan).compile().as_text()
+        jax.ShapeDtypeStruct((2,), jnp.int32), [plan, plan]
+    ).compile().as_text()
     return builder, step
 
 
 def test_programs_keep_their_names(model0_programs):
+    """The step, lowered with the list of plans it stacks itself, is still
+    the module ``jit__step``; the builder is ``jit__lambda``."""
     builder, step = model0_programs
     assert _module_name(builder) == "jit__lambda"
     assert _module_name(step) == "jit__step"
@@ -176,3 +178,6 @@ def test_serve_spans_nest_and_carry_their_args(tmp_path):
                         "points_bucket": 64}] * 2
     hits = [s[3]["hit"] for s in spans if s[0] == "serve.plan"]
     assert hits == [0, 0, 1, 1]
+    # the plans handed to the step: one per row of the batch bucket
+    stacks = [s[3] for s in spans if s[0] == "serve.plan_stack"]
+    assert stacks == [{"plans": b["batch_bucket"]} for b in batches]
